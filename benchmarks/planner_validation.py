@@ -10,7 +10,7 @@ import time
 from repro.configs import SHAPES, get_arch
 from repro.configs.base import RunConfig
 from repro.core.hbm_planner import HBMPlanner
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 GiB = 1024 ** 3
 
@@ -18,7 +18,7 @@ ARCHS_TO_CHECK = ["deepseek-7b", "chatglm3-6b", "rwkv6-7b", "whisper-small"]
 
 
 def run(verbose=True):
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=256,
                                 global_batch=4)
     run_cfg = RunConfig(attn_impl="full", remat="nothing",

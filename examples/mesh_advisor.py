@@ -10,7 +10,7 @@ import dataclasses
 from repro.configs import SHAPES, get_arch
 from repro.configs.base import RunConfig
 from repro.core.hbm_planner import HBMPlanner
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 GiB = 1024 ** 3
 
@@ -31,7 +31,7 @@ def main(argv=None):
                                 global_batch=8)
     run = RunConfig(attn_impl="blocked", remat="boundaries",
                     compute_dtype="bfloat16", microbatches=2)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     planner = HBMPlanner(leeway=0.05)
     rep = planner.plan(cfg, shape, mesh, run=run, anchor_layers=12)

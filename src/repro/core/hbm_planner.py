@@ -49,6 +49,14 @@ def _reduced_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
     return dataclasses.replace(cfg, n_layers=n_layers)
 
 
+def compiled_bytes(compiled) -> float:
+    """Per-device bytes a compiled step holds: live arguments + outputs +
+    temporaries, less the outputs that alias donated arguments."""
+    ma = compiled.memory_analysis()
+    return float(ma.argument_size_in_bytes + ma.output_size_in_bytes +
+                 ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
 @dataclass
 class PlanReport:
     job: str
@@ -78,16 +86,12 @@ class HBMPlanner:
         """Per-device bytes of the job's step on `mesh` via AOT compile."""
         from repro.launch.dryrun import build_lowered
         lowered, _ = build_lowered(cfg, shape, mesh, run)
-        ma = lowered.compile().memory_analysis()
-        return float(ma.argument_size_in_bytes + ma.output_size_in_bytes +
-                     ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+        return compiled_bytes(lowered.compile())
 
-    def plan(self, cfg: ModelConfig, shape: ShapeConfig, profile_mesh,
-             run: Optional[RunConfig] = None,
-             anchor_layers: Optional[int] = None,
-             select: bool = True) -> PlanReport:
-        t0 = time.monotonic()
-        n_dev = profile_mesh.devices.size
+    @staticmethod
+    def ladder(cfg: ModelConfig,
+               anchor_layers: Optional[int] = None) -> List[int]:
+        """The depths `plan` profiles, shallowest first."""
         anchor = anchor_layers or max(2, min(cfg.n_layers // 4, 12))
         # lo >= 2: a length-1 scan is inlined by XLA and its buffer liveness
         # differs from the scanned steady state — the analogue of the
@@ -99,7 +103,15 @@ class HBMPlanner:
         if cfg.cross_attn is not None:
             lo = cfg.cross_attn.period
             anchor = max(anchor, 3 * lo)
-        ladder = integer_ladder(anchor, n=5, lo=lo)
+        return integer_ladder(anchor, n=5, lo=lo)
+
+    def plan(self, cfg: ModelConfig, shape: ShapeConfig, profile_mesh,
+             run: Optional[RunConfig] = None,
+             anchor_layers: Optional[int] = None,
+             select: bool = True) -> PlanReport:
+        t0 = time.monotonic()
+        n_dev = profile_mesh.devices.size
+        ladder = self.ladder(cfg, anchor_layers)
         mems = []
         for L in ladder:
             small = _reduced_depth(cfg, L)

@@ -29,7 +29,7 @@ from typing import List, Optional, Tuple
 
 from repro.core.catalog import ClusterConfig, medium_config
 from repro.core.history import ExecutionHistory
-from repro.launch.roofline import PEAK_FLOPS
+from repro.launch.roofline import chip_peaks
 
 DEFAULT_OVERHEAD_GIB = 2.0      # Spark/Hadoop+OS per node (paper §III-D)
 
@@ -78,7 +78,8 @@ def config_capacity(config: ClusterConfig) -> float:
     node = config.node
     peak = getattr(node, "peak_tflops", 0.0) or 0.0
     if peak > 0.0:
-        return (peak * 1e12 / PEAK_FLOPS) * config.scale_out
+        v5e = chip_peaks("TPU v5 lite")
+        return (peak * 1e12 / v5e.flops) * config.scale_out
     return float(config.total_cores)
 
 

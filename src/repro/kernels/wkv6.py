@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import cumsum_rows, dot, row_to_col
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
             n_chunks: int, chunk: int):
@@ -37,12 +39,12 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = w_ref[0, 0].astype(jnp.float32)       # log-decay <= 0
-    u = u_ref[0].astype(jnp.float32)           # (K,)
+    u = u_ref[0].astype(jnp.float32)           # (1, K)
     S = s_scr[...]                              # (K, V)
 
-    cum = jnp.cumsum(lw, axis=0)               # (Q, K)
-    cum_prev = cum - lw
     Q = r.shape[0]
+    cum = cumsum_rows(lw)                      # (Q, K)
+    cum_prev = cum - lw
     # A[t, j] = sum_K r_t k_j exp(cum_prev[t] - cum[j]),  j < t
     expo = cum_prev[:, None, :] - cum[None, :, :]          # (t, j, K)
     expo = jnp.minimum(expo, 0.0)
@@ -51,18 +53,15 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
     tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) > \
         jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     A = jnp.where(tri, A, 0.0)
-    y = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)            # (Q,)
-    y = y + diag[:, None] * v
-    y = y + jax.lax.dot_general(r * jnp.exp(cum_prev), S,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    y = dot(A, v)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)      # (Q, 1)
+    y = y + diag * v
+    y = y + dot(r * jnp.exp(cum_prev), S)
     # state update
-    tail = jnp.exp(cum[-1:, :] - cum)                      # (Q, K)
-    s_scr[...] = S * jnp.exp(cum[-1])[:, None] + jax.lax.dot_general(
-        (k * tail), v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    last = cum[Q - 1:Q, :]                                 # (1, K)
+    tail = jnp.exp(last - cum)                             # (Q, K)
+    s_scr[...] = S * row_to_col(jnp.exp(last)) + dot(k * tail, v,
+                                                      ((0,), (0,)))
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
 
@@ -98,12 +97,14 @@ def wkv6(r, k, v, lw, u, *, chunk: int = 16, interpret: bool = False):
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, K), lambda b, h, c: (h, 0)),
+            # one (1, K) row per head: a (1, K) block of a (H, K) array
+            # breaks the TPU (8, 128) tiling rule, of a (H, 1, K) one not
+            pl.BlockSpec((1, 1, K), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, K), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, n_chunks * chunk, K),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u)
+    )(rt, kt, vt, wt, u.reshape(H, 1, K))
     return jnp.moveaxis(y, 1, 2)[:, :S]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input-shape) cell against the production mesh — single-pod
 (16,16) data x model and multi-pod (2,16,16) pod x data x model — with no
@@ -19,6 +16,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -28,8 +26,7 @@ import jax.numpy as jnp
 from repro.configs import (ARCHS, SHAPES, get_arch, shape_applicable,
                            cell_id)
 from repro.configs.base import RunConfig
-from repro.launch.mesh import (compat_cost_analysis, make_production_mesh,
-                               mesh_config)
+from repro.launch.mesh import make_production_mesh, mesh_config
 from repro.launch.presets import preset_run
 from repro.launch.hlo_costs import analyze as hlo_analyze
 from repro.launch.roofline import model_flops, roofline_from_hlo
@@ -123,7 +120,7 @@ def run_cell(cfg, shape, mesh, run: RunConfig = None, hlo_out: str = None):
     compiled = lowered.compile()
     t_compile = time.monotonic() - t0
     ma = compiled.memory_analysis()
-    cost = compat_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     if hlo_out:
         with open(hlo_out, "w") as f:
@@ -198,6 +195,9 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    # the production meshes need 512 fake host devices; set before the
+    # first jax call initializes the backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     os.makedirs(args.out, exist_ok=True)

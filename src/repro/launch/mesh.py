@@ -1,83 +1,34 @@
-"""Production mesh builders.
+"""Mesh builders.
 
 `make_production_mesh` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state — the dry-run sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init,
-while smoke tests and benches see 1 device.
+XLA_FLAGS=--xla_force_host_platform_device_count=512 in its `main()` before
+first jax init, while smoke tests and benches see 1 device.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-try:                                    # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: meshes are Auto-only
-    AxisType = None
-
-from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD
-
-HAS_AXIS_TYPE = AxisType is not None
-
-def compat_shard_map(body, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map` across jax versions: top-level API with `check_vma`
-    on new jax, `jax.experimental.shard_map.shard_map` with the older
-    `check_rep` spelling of the same knob otherwise."""
-    kw = {} if check_vma is None else {"check_vma": check_vma}
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
+from repro.configs.base import MeshConfig
 
 
-def compat_cost_analysis(compiled) -> dict:
-    """`compiled.cost_analysis()` across jax versions: newer jax returns
-    one dict, jax <= 0.4.x a list with one dict per partitioned program —
-    normalize to the first (host-local) program's dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return cost
-
-
-def compat_axis_size(axis_name: str):
-    """`lax.axis_size` inside a shard_map/pmap body across jax versions;
-    older jax uses the classic constant-folded `psum(1, axis)` idiom."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def compat_make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """`jax.make_mesh` with Auto axis types where the installed jax supports
-    them, plain mesh otherwise (older jax is Auto-only, so semantics match)."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None):
+    """`jax.make_mesh` with Auto axes. The sharding rules place arrays
+    through NamedSharding and sharding constraints and leave the rest to
+    the partitioner; `jax.make_mesh` alone would make every axis
+    Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_mesh(cfg: MeshConfig):
-    return compat_make_mesh(cfg.shape, cfg.axes)
-
-
-def make_local_mesh(model: int = 1, data: Optional[int] = None):
-    """Mesh over whatever devices exist (tests / CPU runs)."""
-    n = len(jax.devices())
-    if data is None:
-        data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def mesh_config(mesh) -> MeshConfig:

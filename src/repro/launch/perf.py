@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimb driver: run named variants of the three chosen cells,
 record the roofline terms per variant into experiments/perf/.
 
@@ -15,6 +12,7 @@ count, MoE dispatch and gradient compression are the knobs.
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -23,12 +21,12 @@ import jax
 from repro.configs import SHAPES, get_arch
 from repro.configs.base import MeshConfig, RunConfig
 from repro.launch.dryrun import run_cell
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import preset_run
 
 
 def mesh_of(shape, axes=("data", "model")):
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def ds7b_variants():
@@ -141,6 +139,9 @@ def main():
     ap.add_argument("--out", default="experiments/perf")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    # every cell compiles for 256 chips on fake host devices; set before
+    # the first jax call initializes the backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     os.makedirs(args.out, exist_ok=True)
 
     cells = sorted(CELLS) if args.all else [args.cell]

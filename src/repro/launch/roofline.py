@@ -14,8 +14,8 @@ optimized HLO and charge each op its ring-algorithm wire bytes per device:
     all-to-all(S, group n):          S * (n-1)/n
     collective-permute(S):           S
 
-Hardware constants (v5e class, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (brief-specified).
+Hardware peaks per chip come from `PEAKS`, keyed by jax's `device_kind`;
+the terms here use the v5e entry, the chip the dry-run targets.
 """
 from __future__ import annotations
 
@@ -23,9 +23,37 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float            # dense bf16 FLOP/s
+    hbm_bytes: float        # HBM capacity
+    hbm_bw: float           # HBM bytes/s
+    ici_bw: float           # interconnect bytes/s per link
+    source: str
+
+
+_V5E = ChipPeaks(
+    flops=197e12, hbm_bytes=16e9, hbm_bw=819e9,
+    ici_bw=50e9,            # 1,600 Gbit/s of chip-to-chip ICI over 4 links
+    source='Google Cloud documentation, "TPU v5e"')
+
+# published peaks by `jax.devices()[i].device_kind`: a v5e reports itself
+# as "TPU v5 lite", and jax's mesh utilities also know it as "TPU v5e"
+PEAKS: Dict[str, ChipPeaks] = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip. A kind missing from `PEAKS` is an error, never a
+    default: a roofline share against another chip's peaks is wrong."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+V5E = chip_peaks("TPU v5 lite")
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -132,7 +160,7 @@ class Roofline:
         model_flops / (bound_s * peak) per device."""
         if self.bound_s <= 0:
             return 0.0
-        return self.model_flops / (self.bound_s * PEAK_FLOPS)
+        return self.model_flops / (self.bound_s * V5E.flops)
 
 
 def roofline_from(cost: dict, coll: CollectiveStats, n_devices: int,
@@ -140,9 +168,9 @@ def roofline_from(cost: dict, coll: CollectiveStats, n_devices: int,
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     return Roofline(
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=byts / HBM_BW,
-        collective_s=coll.wire_bytes / ICI_BW,
+        compute_s=flops / V5E.flops,
+        memory_s=byts / V5E.hbm_bw,
+        collective_s=coll.wire_bytes / V5E.ici_bw,
         flops_per_dev=flops,
         bytes_per_dev=byts,
         coll_bytes_per_dev=coll.wire_bytes,
@@ -158,9 +186,9 @@ def roofline_from_hlo(hc, n_devices: int, model_flops_total: float,
     master — outside the parsed dot set)."""
     byts = hc.dot_bytes + extra_hbm_bytes
     return Roofline(
-        compute_s=hc.dot_flops / PEAK_FLOPS,
-        memory_s=byts / HBM_BW,
-        collective_s=hc.coll_wire_bytes / ICI_BW,
+        compute_s=hc.dot_flops / V5E.flops,
+        memory_s=byts / V5E.hbm_bw,
+        collective_s=hc.coll_wire_bytes / V5E.ici_bw,
         flops_per_dev=hc.dot_flops,
         bytes_per_dev=byts,
         coll_bytes_per_dev=hc.coll_wire_bytes,

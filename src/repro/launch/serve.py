@@ -1,6 +1,10 @@
 """Serving launcher: drive the continuous-batching engine from the CLI.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --requests 16
+Runs the published widths of `--arch`; `--reduced` swaps in the tiny
+same-family config (CPU).
+
+  PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --reduced \
+      --requests 16
 """
 from __future__ import annotations
 
@@ -12,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.configs.base import RunConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.serve.engine import Request, ServeEngine
 
@@ -19,6 +24,8 @@ from repro.serve.engine import Request, ServeEngine
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (CPU)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -27,8 +34,11 @@ def main(argv=None):
     ap.add_argument("--int8-kv", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
-    cfg = get_arch(args.arch).reduced()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     run = RunConfig(attn_impl="full", remat="nothing",
                     compute_dtype="float32",
                     kv_cache_dtype="int8" if args.int8_kv else "compute")

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.configs.base import RunConfig
 from repro.data.pipeline import ShardedLoader, SyntheticLMDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.optim import AdamWConfig
 from repro.train.loop import LoopConfig, train_loop
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--grad-compression", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig, RunConfig
 from repro.models import layers as L
-from repro.launch.mesh import compat_axis_size, compat_shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +201,7 @@ def moe_ep(params, x, cfg: ModelConfig, run: RunConfig, mesh):
     body = functools.partial(_moe_ep_body, m=m, fsdp=run.fsdp_experts,
                              axis_names=tuple(mesh.axis_names))
     shared = params.get("shared", {})
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None),
                   P("model", None, ff_spec), P("model", None, ff_spec),
@@ -232,8 +231,8 @@ def _moe_ep_a2a_body(x, router_w, w_gate, w_up, w_down, shared, *,
     T = B_l * S
     gates, idx, aux = route(router_w, xt, m)
     E_local = w_gate.shape[0]                 # experts on THIS device
-    M = compat_axis_size("model")
-    D = compat_axis_size(data_axis)
+    M = lax.axis_size("model")
+    D = lax.axis_size(data_axis)
     m_idx = lax.axis_index("model")
     # expert e lives on (m = e // (D*E_local), d = (e // E_local) % D)
     # this m-shard only handles its own experts; others contribute via the
@@ -326,7 +325,7 @@ def moe_ep_a2a(params, x, cfg: ModelConfig, run: RunConfig, mesh):
                              axis_names=tuple(mesh.axis_names))
     shared = params.get("shared", {})
     espec = P(("model", "data"), None, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None), espec, espec,
                   P(("model", "data"), None, None), P()),

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import compat_axis_size, compat_shard_map
 
 
 def _pipeline_body(stage_params, x_micro, *, fn: Callable, n_micro: int,
@@ -34,7 +33,7 @@ def _pipeline_body(stage_params, x_micro, *, fn: Callable, n_micro: int,
     x_micro: (n_micro, B, S, d) — full input stream, replicated over
     'pipe' (stage 0 reads it; others ignore). Returns (n_micro, B, S, d)
     outputs (valid on every stage after the final broadcast)."""
-    n_stages = compat_axis_size(axis)
+    n_stages = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     ticks = n_micro + n_stages - 1
     fwd_perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -82,7 +81,7 @@ def pipeline_apply(fn: Callable, stacked_params, x, mesh, *,
                              axis=axis)
     # stacked params: leading layer axis sharded over the pipe axis
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    out = compat_shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),

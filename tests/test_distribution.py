@@ -18,9 +18,8 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # every snippet builds meshes through the AxisType compat shim so the
-    # suite runs on jax installs without jax.sharding.AxisType (< 0.5)
-    code = ("from repro.launch.mesh import compat_make_mesh\n"
+    # every snippet builds its meshes with Auto axes, as the program does
+    code = ("from repro.launch.mesh import make_mesh\n"
             + textwrap.dedent(code))
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env,
@@ -56,7 +55,7 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(make_train_step(model, acfg, None))(state, batch)
 
         # sharded over (2 data, 4 model)
-        mesh = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         p_specs = param_specs(state.params, mesh, run)
         o_specs = opt_state_specs(state.opt, p_specs, state.params, mesh, run)
         sh = TrainState(
@@ -88,7 +87,7 @@ def test_moe_ep_sharded_matches_dense():
         params = M.init_moe(jax.random.PRNGKey(0), cfg)
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
         dense, aux_d = M.moe_dense(params, x, cfg)
-        mesh = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg_hi = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=float(cfg.moe.n_experts)))
         ep, aux_e = jax.jit(lambda p, x: M.moe_ep(p, x, cfg_hi, run, mesh))(
@@ -115,7 +114,7 @@ def test_moe_ep_a2a_matches_dense():
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1),
                                     (4, 16, cfg.d_model))
         dense, _ = M.moe_dense(params, x, cfg)
-        mesh = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg_hi = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=float(cfg.moe.n_experts * 4),
             impl='ep_a2a'))
@@ -138,7 +137,7 @@ def test_dryrun_cell_multipod_small():
         import jax, json
         from repro.configs import get_arch, SHAPES
         from repro.launch.dryrun import run_cell
-        mesh = compat_make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         rec = run_cell(get_arch('whisper-small'), SHAPES['train_4k'], mesh)
         assert rec['roofline']['dominant'] in ('compute', 'memory',
                                                'collective')
@@ -157,7 +156,7 @@ def test_sharding_rules_divisibility_fallback():
         from repro.configs.base import RunConfig
         from repro.sharding.rules import param_specs
         from repro.models.model import Model
-        mesh = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         run = RunConfig()
         # whisper: 12 heads not divisible by 4? 12 % 4 == 0 -> sharded;
         # chatglm kv heads = 2 not divisible by 4 -> replicated
@@ -181,7 +180,7 @@ def test_pipeline_parallelism_fwd_and_grad():
         import jax, jax.numpy as jnp
         from jax import lax
         from repro.sharding.pipeline import pipeline_apply
-        mesh = compat_make_mesh((4,), ('pipe',))
+        mesh = make_mesh((4,), ('pipe',))
         L, d = 8, 16
         W = 0.3 * jax.random.normal(jax.random.PRNGKey(0), (L, d, d))
         def stage_fn(stage_w, x):
@@ -208,13 +207,13 @@ def test_elastic_checkpoint_restore_across_meshes():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_checkpoint
 
-        mesh_a = compat_make_mesh((4, 2), ('data', 'model'))
+        mesh_a = make_mesh((4, 2), ('data', 'model'))
         x = jnp.arange(64.0).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh_a, P('data', 'model')))
         d = tempfile.mkdtemp()
         save_checkpoint(d, 1, {'x': xs})
         # restore onto a *different* mesh layout
-        mesh_b = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh_b = make_mesh((2, 4), ('data', 'model'))
         like = {'x': jax.ShapeDtypeStruct((8, 8), jnp.float32)}
         shard = {'x': NamedSharding(mesh_b, P('model', 'data'))}
         got, _ = restore_checkpoint(d, 1, like, shardings=shard)

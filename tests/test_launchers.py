@@ -20,7 +20,7 @@ def test_train_cli_runs_and_learns():
 
 def test_serve_cli_runs():
     from repro.launch.serve import main
-    done = main(["--arch", "deepseek-7b", "--requests", "3",
+    done = main(["--arch", "deepseek-7b", "--reduced", "--requests", "3",
                  "--slots", "2", "--max-new", "4"])
     assert len(done) == 3
     assert all(len(r.out_tokens) == 4 for r in done)

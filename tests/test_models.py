@@ -203,13 +203,13 @@ def test_int8_kv_cache_decode_accuracy():
 def test_moe_dense_vs_ep_capacity():
     """EP sort/scatter dispatch == dropless dense path when capacity is
     ample (single device shard_map over a trivial mesh)."""
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import moe as M
     cfg = ARCHS["olmoe-1b-7b"].reduced()
     params = M.init_moe(KEY, cfg)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(4), (2, 16, cfg.d_model))
     dense_out, aux_d = M.moe_dense(params, x, cfg)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     import dataclasses
     cfg_hi = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))

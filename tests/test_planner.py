@@ -6,16 +6,15 @@ from repro.configs import SHAPES, get_arch
 from repro.configs.base import RunConfig
 from repro.core.hbm_planner import HBMPlanner, _reduced_depth
 from repro.core.catalog import tpu_catalog
-# AxisType only exists on newer jax; the compat helper feature-detects it so
-# this module collects (and the planner tests run) on older versions too.
-from repro.launch.mesh import compat_make_mesh
+# Auto axes: the planner's sharding rules are written for them
+from repro.launch.mesh import make_mesh
 
 GiB = 1024 ** 3
 
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _small_shape():

@@ -6,9 +6,8 @@ import pytest
 from jax import lax
 
 from repro.launch.hlo_costs import analyze, parse_computations
-from repro.launch.mesh import compat_cost_analysis
-from repro.launch.roofline import (Roofline, model_flops, roofline_from_hlo,
-                                   PEAK_FLOPS)
+from repro.launch.roofline import (PEAKS, Roofline, chip_peaks, model_flops,
+                                   roofline_from_hlo)
 from repro.configs import get_arch, SHAPES
 
 
@@ -71,7 +70,7 @@ def test_xla_cost_analysis_undercounts_loops():
         return y
 
     compiled = _compile(f, jax.ShapeDtypeStruct((64, 64), jnp.float32))
-    xla_flops = compat_cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     ours = analyze(compiled.as_text(), 1).dot_flops
     assert ours == 16 * 2 * 64 ** 3
     assert xla_flops < ours / 8          # massive undercount
@@ -80,10 +79,22 @@ def test_xla_cost_analysis_undercounts_loops():
 def test_roofline_dominant_term():
     r = Roofline(compute_s=1.0, memory_s=2.0, collective_s=0.5,
                  flops_per_dev=1.0, bytes_per_dev=1.0, coll_bytes_per_dev=1.0,
-                 model_flops=PEAK_FLOPS)
+                 model_flops=chip_peaks("TPU v5 lite").flops)
     assert r.dominant == "memory"
     assert r.bound_s == 2.0
     assert r.mfu_bound == pytest.approx(0.5)
+
+
+def test_chip_peaks_table():
+    """The v5e entry keeps the published numbers under both kind names;
+    a kind without an entry is an error, not a default."""
+    for kind in ("TPU v5 lite", "TPU v5e"):
+        p = chip_peaks(kind)
+        assert (p.flops, p.hbm_bw, p.ici_bw) == (197e12, 819e9, 50e9)
+        assert p.source
+    assert all(p.source for p in PEAKS.values())
+    with pytest.raises(KeyError, match="cpu"):
+        chip_peaks("cpu")
 
 
 def test_model_flops_conventions():
