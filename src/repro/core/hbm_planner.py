@@ -17,6 +17,14 @@ The extrapolation target is aggregate HBM = per-device bytes x devices,
 the analogue of the paper's total-cluster-memory requirement; per-chip
 feasibility is additionally checked on the (divided) per-device estimate.
 Validation against ground-truth full compiles: EXPERIMENTS.md §Planner.
+
+Telemetry (while the process registry is enabled): each
+`profile_memory` is a span `planner.profile` (attributes `n_layers`,
+`seq_len`, `batch`) with the children `planner.lower` (eval_shape, the
+trace and the lowering), `planner.compile` (XLA's compile, whose
+`compile_s` the span system adds from jax.monitoring) and
+`planner.memory` (memory_analysis). Under a decision they nest inside
+`pipeline.acquire`.
 """
 from __future__ import annotations
 
@@ -33,6 +41,7 @@ from repro.core.history import ExecutionHistory
 from repro.core.memory_model import LinearMemoryModel, fit_memory_model
 from repro.core.sampling import integer_ladder
 from repro.core.selector import Selection, select_bfa
+from repro.telemetry import default_registry, span_if
 
 GiB = 1024 ** 3
 TPU_OVERHEAD_GIB = 1.25       # XLA runtime / infeed / collective scratch
@@ -85,8 +94,15 @@ class HBMPlanner:
                        run: Optional[RunConfig] = None) -> float:
         """Per-device bytes of the job's step on `mesh` via AOT compile."""
         from repro.launch.dryrun import build_lowered
-        lowered, _ = build_lowered(cfg, shape, mesh, run)
-        return compiled_bytes(lowered.compile())
+        on = default_registry().enabled
+        with span_if(on, "planner.profile", n_layers=cfg.n_layers,
+                     seq_len=shape.seq_len, batch=shape.global_batch):
+            with span_if(on, "planner.lower"):
+                lowered, _ = build_lowered(cfg, shape, mesh, run)
+            with span_if(on, "planner.compile"):
+                compiled = lowered.compile()
+            with span_if(on, "planner.memory"):
+                return compiled_bytes(compiled)
 
     @staticmethod
     def ladder(cfg: ModelConfig,

@@ -12,6 +12,14 @@ Greedy or temperature sampling. This is the serving analogue the paper's
 "job" maps onto for decode shapes, and the engine the serve_demo example
 drives.
 
+Telemetry (`telemetry=`, default the process registry): each tick is one
+root span `engine.tick` (attributes `slots` stepped and output `tokens`
+appended) with the children `engine.admit` (admission and slot resets),
+`engine.dispatch` (the batch and the jitted step's async dispatch),
+`engine.fetch` (the logits to the host: where the host waits on the
+device) and `engine.sample` (the per-slot loop). The step is jitted as
+`decode_step`, so profiler traces name its program `jit_decode_step`.
+
 `AllocationEndpoint` exposes the allocator subsystem
 (repro.allocator.service) on the same serving surface: dict-in/dict-out
 allocation requests, optionally attached to a `ServeEngine` via
@@ -31,7 +39,7 @@ import numpy as np
 from repro.allocator.service import (AllocationRequest, AllocationResponse,
                                      AllocationService)
 from repro.models.model import Model
-from repro.telemetry import span_if
+from repro.telemetry import MetricsRegistry, default_registry, span_if
 
 
 @dataclass
@@ -49,7 +57,8 @@ class Request:
 class ServeEngine:
     def __init__(self, model: Model, params, slots: int, max_len: int,
                  eos_id: Optional[int] = None, seed: int = 0,
-                 allocator: Optional[AllocationService] = None):
+                 allocator: Optional[AllocationService] = None,
+                 telemetry: Optional[MetricsRegistry] = None):
         self.model = model
         self.params = params
         self.slots = slots
@@ -63,11 +72,15 @@ class ServeEngine:
         self._feed: List[List[int]] = [[] for _ in range(slots)]
         self._last_token = np.zeros((slots,), np.int32)
         self.allocation_endpoint: Optional[AllocationEndpoint] = None
+        self.telemetry = telemetry if telemetry is not None \
+            else default_registry()
         if allocator is not None:
             self.attach_allocator(allocator)
 
-        self._step = jax.jit(
-            lambda p, b, c: model.decode_step(p, b, c, None))
+        def decode_step(params, batch, caches):
+            return model.decode_step(params, batch, caches, None)
+
+        self._step = jax.jit(decode_step)
 
     # -- public ------------------------------------------------------------
     def submit(self, req: Request):
@@ -95,30 +108,45 @@ class ServeEngine:
 
     # -- internals ----------------------------------------------------------
     def tick(self):
-        self._admit()
-        if not any(self.active):
-            return
-        batch = {"tokens": jnp.asarray(self._last_token)[:, None]}
-        extras = self._extras()
-        batch.update(extras)
-        logits, self.caches = self._step(self.params, batch, self.caches)
-        logits = np.asarray(logits[:, 0])           # (slots, V)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            if self._feed[i]:
-                # still teacher-forcing the prompt
-                self._last_token[i] = self._feed[i].pop(0)
-                continue
-            tok = self._sample(logits[i], req.temperature)
-            req.out_tokens.append(int(tok))
-            self._last_token[i] = tok
-            if (len(req.out_tokens) >= req.max_new_tokens or
-                    (self.eos_id is not None and tok == self.eos_id)):
-                req.done = True
-                req.finished_at = time.monotonic()
-                self.finished.append(req)
-                self.active[i] = None
+        on = self.telemetry.enabled
+        with span_if(on, "engine.tick") as sp:
+            with span_if(on, "engine.admit"):
+                self._admit()
+            slots = sum(r is not None for r in self.active)
+            tokens = self._step_slots(on) if slots else 0
+            if sp is not None:
+                sp.attrs["slots"] = slots
+                sp.attrs["tokens"] = tokens
+
+    def _step_slots(self, on: bool) -> int:
+        """One batched step over the active slots; returns the output
+        tokens appended."""
+        with span_if(on, "engine.dispatch"):
+            batch = {"tokens": jnp.asarray(self._last_token)[:, None]}
+            batch.update(self._extras())
+            logits, self.caches = self._step(self.params, batch, self.caches)
+        with span_if(on, "engine.fetch"):
+            logits = np.asarray(logits[:, 0])       # (slots, V)
+        tokens = 0
+        with span_if(on, "engine.sample"):
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                if self._feed[i]:
+                    # still teacher-forcing the prompt
+                    self._last_token[i] = self._feed[i].pop(0)
+                    continue
+                tok = self._sample(logits[i], req.temperature)
+                req.out_tokens.append(int(tok))
+                tokens += 1
+                self._last_token[i] = tok
+                if (len(req.out_tokens) >= req.max_new_tokens or
+                        (self.eos_id is not None and tok == self.eos_id)):
+                    req.done = True
+                    req.finished_at = time.monotonic()
+                    self.finished.append(req)
+                    self.active[i] = None
+        return tokens
 
     def _admit(self):
         for i in range(self.slots):

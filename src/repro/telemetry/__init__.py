@@ -16,15 +16,22 @@ registry by tests/test_telemetry.py).
                recent on-trace (value, trace_id) as an EXEMPLAR.
   spans.py     `span(name, **attrs)` context manager -> nested,
                thread-aware span trees via `contextvars`, recorded into
-               a bounded `TraceRing` when the root closes. Every span
-               carries trace/span/parent ids; `span(..., parent=ctx)`
-               adopts a REMOTE parent across process edges.
+               a bounded `TraceRing` when the root closes (the process
+               ring holds `DEFAULT_RING_CAP` roots and says how late a
+               root it lost ended). Every span carries trace/span/parent
+               ids; `span(..., parent=ctx)` adopts a REMOTE parent
+               across process edges. Every span keeps its monotonic
+               start; once JAX is loaded each also writes a
+               `jax.profiler.TraceAnnotation` twin onto the profiler's
+               host plane, and `jax.monitoring` compile phases land on
+               the innermost open span as `jaxpr_s`/`mlir_s`/
+               `compile_s`/`compiles`.
   sampling.py  `AdaptiveSampler`: raises the pipeline's warm-path
                1-in-8 sampling toward 1-in-1 while windowed stage p99
                drifts past a gate, decays back on recovery (hysteresis);
                `FixedSampler` keeps a constant rate.
-  export.py    snapshots as JSON (`render_json`) or Prometheus text
-               (`render_prometheus`, with OpenMetrics exemplars); fleet
+  export.py    snapshots as Prometheus text (`render_prometheus`,
+               with OpenMetrics exemplars); fleet
                aggregation by publishing periodic snapshots into the
                reserved `__telemetry__` namespace of any
                `repro.state.StateBackend` (`publish_snapshot` /
@@ -80,6 +87,18 @@ Distributed tracing (how one request becomes ONE tree):
 
 Where each span/metric hangs (the observability map):
 
+  ServeEngine          root span `engine.tick` per tick (attrs `slots`,
+  (repro.serve)        `tokens`) with children `engine.admit` /
+                       `.dispatch` / `.fetch` / `.sample`; `fetch` is
+                       where the host waits on the device. Read by the
+                       chip benchmark's `host_ms.decode` and
+                       `host_bound_idle.decode`.
+  HBMPlanner           span `planner.profile` per ladder point (attrs
+  (repro.core)         `n_layers`, `seq_len`, `batch`) with children
+                       `planner.lower` / `.compile` / `.memory`, inside
+                       `pipeline.acquire`. Read by the chip benchmark's
+                       `lower_s` and `compile_s`.
+
   AllocationPipeline   histograms `pipeline.stage.<stage>.seconds`;
   (repro.pipeline)     counters `pipeline.warm_start.{hits,misses}`;
                        spans `pipeline.warm_start` / `.acquire` / `.fit`
@@ -127,7 +146,7 @@ from repro.telemetry.export import (KEY_FIELDS, TELEMETRY_NS, TRACES_NS,
                                     TelemetryPublisher, aggregate_fleet,
                                     fleet_snapshot, fleet_traces,
                                     publish_snapshot, publish_traces,
-                                    render_json, render_prometheus,
+                                    render_prometheus,
                                     shard_heat, stitch_fleet_traces)
 from repro.telemetry.logs import StructuredLogger
 from repro.telemetry.metrics import (DEFAULT_BUCKETS, Counter, Gauge,
@@ -151,7 +170,7 @@ __all__ = [
     "current_span", "current_trace_context", "default_registry",
     "default_ring", "fleet_snapshot", "fleet_traces", "new_span_id",
     "publish_snapshot", "publish_traces", "quantile_from_buckets",
-    "render_json", "render_prometheus", "resolve_sampler",
+    "render_prometheus", "resolve_sampler",
     "set_default_registry", "shard_heat", "span", "span_if",
     "stitch_fleet_traces",
 ]

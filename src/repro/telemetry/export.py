@@ -1,9 +1,8 @@
-"""Exporters: registry snapshots as JSON, Prometheus text, and fleet
-snapshots published through any `repro.state.StateBackend`.
+"""Exporters: registry snapshots as Prometheus text, and fleet snapshots
+published through any `repro.state.StateBackend`.
 
-Local forms:
+Local form (`registry.snapshot()` is already a JSON-safe dict):
 
-  render_json(registry)        one JSON object (the raw `snapshot()`).
   render_prometheus(registry)  Prometheus text exposition: counters as
                                `<name>_total`, gauges verbatim,
                                histograms as cumulative `_bucket{le=..}`
@@ -43,6 +42,8 @@ local roots carrying the caller's trace_id/parent_id — see
 repro.telemetry.spans and repro.state.daemon):
 
   publish_traces(backend, "svc-4711")         # push default_ring roots
+                                              # (the newest that fit
+                                              # TRACES_ROW_BYTES)
   fleet_traces(backend)                       # {source: [root, ...]}
   stitch_fleet_traces(fleet)                  # [cross-process trees]
 
@@ -68,13 +69,14 @@ TRACES_NS = "__traces__"
 # (later snapshot per source wins; see repro.state.compaction.fold_log)
 KEY_FIELDS = ("source",)
 
+# span JSON one published trace row may carry: half the daemon's frame
+# cap (repro.state.transport.MAX_FRAME_BYTES, 8 MiB), the headroom its
+# client's batches keep too. A full process ring of engine ticks is
+# about 16 MB of JSON, so a row keeps the newest roots that fit.
+TRACES_ROW_BYTES = 4 * 1024 * 1024
+
 
 # -- local renderers ----------------------------------------------------------
-
-def render_json(registry: MetricsRegistry, indent: Optional[int] = None,
-                ) -> str:
-    return json.dumps(registry.snapshot(), indent=indent, sort_keys=True)
-
 
 def _prom_name(name: str) -> str:
     out = []
@@ -242,12 +244,24 @@ def shard_heat(fleet: Dict[str, Dict],
 def publish_traces(backend, source: str, ring: Optional[TraceRing] = None,
                    namespace: str = TRACES_NS) -> Dict:
     """Append this process's finished root spans (as dicts) to the
-    shared trace log. Defaults to the process `default_ring()`. Returns
-    the published row."""
+    shared trace log: the newest roots whose JSON fits
+    `TRACES_ROW_BYTES`, in ring order, with `omitted` counting the older
+    ones left out. Defaults to the process `default_ring()`. Returns the
+    published row."""
     if ring is None:
         ring = default_ring()
-    row = {"source": source, "ts": time.time(),
-           "traces": [s.to_dict() for s in ring.traces()]}
+    roots = ring.traces()
+    kept: List[Dict] = []
+    size = 0
+    for s in reversed(roots):
+        d = s.to_dict()
+        size += len(json.dumps(d)) + 2          # the list's ", "
+        if size > TRACES_ROW_BYTES:
+            break
+        kept.append(d)
+    kept.reverse()
+    row = {"source": source, "ts": time.time(), "traces": kept,
+           "omitted": len(roots) - len(kept)}
     backend.append(namespace, row)
     return row
 

@@ -41,6 +41,19 @@ production hot paths; instrumented code that wants a zero-cost off
 switch uses `span_if(enabled, ...)`, which degrades to a shared no-op
 context manager.
 
+The device trace's timeline: every span also keeps `mono_start`, its
+start on the monotonic clock (`perf_counter`, on Linux the
+CLOCK_MONOTONIC that `time.monotonic()` reads), so a reader can clip
+spans to a window given in monotonic seconds. Once JAX is loaded (this
+module never imports it), each span also enters a
+`jax.profiler.TraceAnnotation` of its own name — its TWIN on the
+profiler's host plane, beside the device's ops in any profiler trace —
+and one process-wide `jax.monitoring` listener adds the compile phases
+a thread runs to the innermost span open on it (`COMPILE_EVENTS`):
+`jaxpr_s` (tracing to a jaxpr), `mlir_s` (lowering to StableHLO),
+`compile_s` (XLA's backend compile) and `compiles` (their count), so a
+tree says which tick recompiled.
+
 `current_trace_context()` returns the innermost open span's
 `{"trace_id", "span_id"}` (or None) — the propagation token clients
 stamp onto wire frames (see repro.state.transport.TRACE_FIELD) and
@@ -50,6 +63,7 @@ from __future__ import annotations
 
 import contextvars
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -64,6 +78,17 @@ _current: "contextvars.ContextVar[Optional[Span]]" = \
 # negligible for bounded rings of short-lived traces.
 _ids = random.Random()
 
+# roots the process ring holds: a 51 s window of 5 ms ticks is 10,200
+DEFAULT_RING_CAP = 16384
+
+# the compile phases jax.monitoring reports, and the span attribute
+# (seconds, summed) each adds to the innermost span open on its thread
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "mlir_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+
 
 def new_span_id() -> str:
     """A fresh 64-bit hex id (used for both trace and span ids)."""
@@ -72,11 +97,13 @@ def new_span_id() -> str:
 
 class Span:
     """One timed block: identity, name, attributes, children, wall
-    seconds. `anchor` is the trace's (epoch, perf_counter) pair — see
-    the module docstring for the clock discipline."""
+    seconds. `anchor` is the trace's (epoch, perf_counter) pair and
+    `mono_start` the span's own start on the monotonic clock — see the
+    module docstring for the clock discipline."""
 
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "started_at", "wall_s", "children", "thread", "anchor")
+                 "started_at", "mono_start", "wall_s", "children",
+                 "thread", "anchor")
 
     def __init__(self, name: str, attrs: Dict):
         self.name = name
@@ -85,6 +112,7 @@ class Span:
         self.span_id: Optional[str] = None
         self.parent_id: Optional[str] = None
         self.started_at = 0.0
+        self.mono_start = 0.0
         self.wall_s = 0.0
         self.children: List[Span] = []
         self.thread = threading.current_thread().name
@@ -116,16 +144,24 @@ class TraceRing:
     """Bounded ring of finished ROOT spans (children live inside their
     roots). Thread-safe; oldest traces fall off the end. `recorded` is
     the monotonic count of roots ever recorded — ring wrap-around never
-    hides throughput from the load benchmarks."""
+    hides throughput from the load benchmarks. `evicted_until` is the
+    latest monotonic end of a root that fell off (None while none has):
+    a window that opens after it has lost none of its roots."""
 
     def __init__(self, cap: int = 256):
         self.cap = cap
         self._ring: "deque[Span]" = deque(maxlen=cap)
         self._recorded = 0
+        self._evicted_until: Optional[float] = None
         self._lock = threading.Lock()
 
     def record(self, span_: Span) -> None:
         with self._lock:
+            if len(self._ring) == self.cap:
+                old = self._ring[0]
+                end = old.mono_start + old.wall_s
+                if self._evicted_until is None or end > self._evicted_until:
+                    self._evicted_until = end
             self._ring.append(span_)
             self._recorded += 1
 
@@ -138,16 +174,23 @@ class TraceRing:
         with self._lock:
             return self._recorded
 
+    @property
+    def evicted_until(self) -> Optional[float]:
+        with self._lock:
+            return self._evicted_until
+
     def clear(self) -> None:
+        """Empty the ring; it then reads as new (nothing evicted)."""
         with self._lock:
             self._ring.clear()
+            self._evicted_until = None
 
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
 
 
-_default_ring = TraceRing()
+_default_ring = TraceRing(DEFAULT_RING_CAP)
 
 
 def default_ring() -> TraceRing:
@@ -169,11 +212,46 @@ def current_trace_context() -> Optional[Dict[str, str]]:
     return {"trace_id": s.trace_id, "span_id": s.span_id}
 
 
+# jax.profiler.TraceAnnotation once JAX is loaded and hooked
+_annotation = None
+_hook_lock = threading.Lock()
+
+
+def _hook_jax():
+    """The twin annotation class, or None while JAX is not loaded. The
+    first call after JAX is loaded registers the compile listener — once
+    per process."""
+    global _annotation
+    if "jax" not in sys.modules:
+        return None
+    with _hook_lock:
+        if _annotation is None:
+            import jax.monitoring
+            import jax.profiler
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_phase)
+            _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _on_compile_phase(event: str, duration: float, **_kw) -> None:
+    key = COMPILE_EVENTS.get(event)
+    if key is None:
+        return
+    s = _current.get()
+    if s is None:
+        return
+    attrs = s.attrs
+    attrs[key] = attrs.get(key, 0.0) + duration
+    if key == "compile_s":
+        attrs["compiles"] = attrs.get("compiles", 0) + 1
+
+
 class _SpanContext:
     """The `span(...)` context manager (a class, not @contextmanager:
     ~2x cheaper to enter and exit, and this sits on hot paths)."""
 
-    __slots__ = ("_span", "_ring", "_parent", "_token", "_t0")
+    __slots__ = ("_span", "_ring", "_parent", "_token", "_t0", "_twin")
 
     def __init__(self, name: str, ring: Optional[TraceRing],
                  parent: Optional[Dict], attrs: Dict):
@@ -203,12 +281,20 @@ class _SpanContext:
             s.anchor = (time.time(), t0)
         s.span_id = new_span_id()
         s.started_at = s.anchor[0] + (t0 - s.anchor[1])
+        s.mono_start = t0
         self._token = _current.set(s)
         self._t0 = t0
+        twin = _annotation or _hook_jax()
+        if twin is not None:
+            twin = twin(s.name)
+            twin.__enter__()
+        self._twin = twin
         return s
 
     def __exit__(self, *exc) -> None:
         s = self._span
+        if self._twin is not None:
+            self._twin.__exit__(None, None, None)
         s.wall_s = time.perf_counter() - self._t0
         _current.reset(self._token)
         parent = _current.get()

@@ -21,8 +21,8 @@ from repro.pipeline import AllocationPipeline, PipelineRequest
 from repro.state import CrispyDaemon, DaemonBackend, InMemoryBackend
 from repro.telemetry import (MetricsRegistry, StructuredLogger, TraceRing,
                              aggregate_fleet, current_span, fleet_snapshot,
-                             publish_snapshot, render_json,
-                             render_prometheus, span, span_if)
+                             publish_snapshot, render_prometheus, span,
+                             span_if)
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
@@ -162,14 +162,6 @@ def _sample_registry() -> MetricsRegistry:
     for v in (0.001, 0.002, 0.004, 0.1):
         h.observe(v)
     return reg
-
-
-def test_render_json_round_trips():
-    reg = _sample_registry()
-    snap = json.loads(render_json(reg))
-    assert snap == reg.snapshot()
-    assert snap["counters"]["req.total"] == 7
-    assert snap["histograms"]["req.seconds"]["count"] == 4
 
 
 def test_render_prometheus_exposition():
