@@ -20,6 +20,17 @@ appended) with the children `engine.admit` (admission and slot resets),
 device) and `engine.sample` (the per-slot loop). The step is jitted as
 `decode_step`, so profiler traces name its program `jit_decode_step`.
 
+Weights: the engine holds only the serving form of the tree it is given
+(`repro.serve.prepare`): every leaf the step reads only through a
+convert to the compute dtype is converted once, on the device, so no
+step converts the stack again; every other leaf is the caller's array.
+The engine keeps no reference to the tree it was given, so the caller's
+float32 weights are freed once the caller lets go of them. The form is
+derived at construction and on every assignment of `params`, each time
+in a root span `engine.prepare` (attributes `cast_leaves`,
+`kept_leaves`, `cast_bytes` of the serving copy; its `wall_s` is the
+time the derivation took).
+
 `AllocationEndpoint` exposes the allocator subsystem
 (repro.allocator.service) on the same serving surface: dict-in/dict-out
 allocation requests, optionally attached to a `ServeEngine` via
@@ -39,6 +50,7 @@ import numpy as np
 from repro.allocator.service import (AllocationRequest, AllocationResponse,
                                      AllocationService)
 from repro.models.model import Model
+from repro.serve.prepare import serving_params
 from repro.telemetry import MetricsRegistry, default_registry, span_if
 
 
@@ -60,7 +72,6 @@ class ServeEngine:
                  allocator: Optional[AllocationService] = None,
                  telemetry: Optional[MetricsRegistry] = None):
         self.model = model
-        self.params = params
         self.slots = slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -81,6 +92,29 @@ class ServeEngine:
             return model.decode_step(params, batch, caches, None)
 
         self._step = jax.jit(decode_step)
+        self.params = params
+
+    @property
+    def params(self):
+        """The weights the step reads: the serving form of the tree last
+        assigned, derived again on every assignment."""
+        return self._served
+
+    @params.setter
+    def params(self, params):
+        on = self.telemetry.enabled
+        with span_if(on, "engine.prepare") as sp:
+            served, mask = serving_params(
+                self._step, params, self._batch(), self.caches,
+                dtype=self.model.compute_dtype)
+            served = jax.block_until_ready(served)
+            if sp is not None:
+                cast = [a for a, m in zip(jax.tree.leaves(served), mask)
+                        if m]
+                sp.attrs.update(
+                    cast_leaves=len(cast), kept_leaves=len(mask) - len(cast),
+                    cast_bytes=sum(a.nbytes for a in cast))
+        self._served = served
 
     # -- public ------------------------------------------------------------
     def submit(self, req: Request):
@@ -122,9 +156,8 @@ class ServeEngine:
         """One batched step over the active slots; returns the output
         tokens appended."""
         with span_if(on, "engine.dispatch"):
-            batch = {"tokens": jnp.asarray(self._last_token)[:, None]}
-            batch.update(self._extras())
-            logits, self.caches = self._step(self.params, batch, self.caches)
+            logits, self.caches = self._step(self.params, self._batch(),
+                                             self.caches)
         with span_if(on, "engine.fetch"):
             logits = np.asarray(logits[:, 0])       # (slots, V)
         tokens = 0
@@ -157,17 +190,19 @@ class ServeEngine:
                 self._feed[i] = list(req.prompt[1:])
                 self._last_token[i] = req.prompt[0]
 
-    def _extras(self) -> Dict:
+    def _batch(self) -> Dict:
+        """The step's batch: each slot's last token, and the family's
+        extra inputs."""
         cfg = self.model.cfg
-        extras = {}
+        batch = {"tokens": jnp.asarray(self._last_token)[:, None]}
         if cfg.family == "vlm":
-            extras["media"] = jnp.zeros(
+            batch["media"] = jnp.zeros(
                 (self.slots, cfg.cross_attn.n_media_tokens, cfg.d_model),
                 jnp.float32)
         if cfg.family == "audio":
-            extras["enc_out"] = jnp.zeros(
+            batch["enc_out"] = jnp.zeros(
                 (self.slots, cfg.encdec.enc_len, cfg.d_model), jnp.float32)
-        return extras
+        return batch
 
     def _sample(self, logits: np.ndarray, temperature: float) -> int:
         if temperature <= 0.0:

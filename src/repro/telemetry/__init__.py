@@ -92,7 +92,10 @@ Where each span/metric hangs (the observability map):
                        `.dispatch` / `.fetch` / `.sample`; `fetch` is
                        where the host waits on the device. Read by the
                        chip benchmark's `host_ms.decode` and
-                       `host_bound_idle.decode`.
+                       `host_bound_idle.decode`. Root span
+                       `engine.prepare` per derivation of the serving
+                       weights (attrs `cast_leaves`, `kept_leaves`,
+                       `cast_bytes`).
   HBMPlanner           span `planner.profile` per ladder point (attrs
   (repro.core)         `n_layers`, `seq_len`, `batch`) with children
                        `planner.lower` / `.compile` / `.memory`, inside

@@ -68,8 +68,12 @@ def test_engine_tick_tree(small_model, ring):
     for rid in range(3):
         eng.submit(Request(rid, prompt=[rid + 1, 2, 3], max_new_tokens=4))
     eng.run()
-    ticks = [r for r in ring.traces() if r.name == "engine.tick"]
-    assert ticks and len(ticks) == len(ring)
+    roots = ring.traces()
+    # the engine's construction prepares the weights, then every root is
+    # a tick
+    assert roots[0].name == "engine.prepare"
+    ticks = [r for r in roots if r.name == "engine.tick"]
+    assert ticks and len(ticks) == len(ring) - 1
     for t in ticks:
         assert [c.name for c in t.children] == [
             "engine.admit", "engine.dispatch", "engine.fetch",
@@ -99,6 +103,23 @@ def test_engine_spans_off_with_a_disabled_registry(small_model, ring):
     eng.submit(Request(0, prompt=[4, 5], max_new_tokens=2))
     assert len(eng.run()) == 1
     assert len(ring) == 0
+
+
+def test_engine_prepare_span(small_model, ring):
+    """A root `engine.prepare` at construction and again on every
+    assignment of `params`. This model computes in float32, so the step
+    converts no leaf and none is cast."""
+    m, p = small_model
+    eng = ServeEngine(m, p, slots=1, max_len=32)
+    eng.params = p
+    preps = ring.traces()
+    assert [r.name for r in preps] == ["engine.prepare"] * 2
+    for r in preps:
+        assert r.attrs["cast_leaves"] == 0 and r.attrs["cast_bytes"] == 0
+        assert r.attrs["kept_leaves"] == len(jax.tree.leaves(p))
+        assert r.wall_s > 0 and not r.children
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng.params),
+                                      jax.tree.leaves(p)))
 
 
 def test_engine_step_is_named(small_model):
