@@ -1,11 +1,12 @@
 """Architecture registry: --arch <id> resolves here."""
 from repro.configs.base import (ModelConfig, MoEConfig, MLAConfig, SSMConfig,
+                                YarnConfig,
                                 HybridConfig, EncDecConfig, CrossAttnConfig,
                                 ShapeConfig, MeshConfig, RunConfig,
                                 SHAPES, TRAIN_4K, PREFILL_32K, DECODE_32K,
                                 LONG_500K, SINGLE_POD, MULTI_POD, cell_id)
 
-from repro.configs.deepseek_v3_671b import CONFIG as _dsv3
+from repro.configs.deepseek_v3_671b import CONFIG as _dsv3, EP32 as _dsv3_ep32
 from repro.configs.olmoe_1b_7b import CONFIG as _olmoe
 from repro.configs.zamba2_7b import CONFIG as _zamba2
 from repro.configs.mistral_large_123b import CONFIG as _mistral
@@ -17,7 +18,7 @@ from repro.configs.llama32_vision_90b import CONFIG as _llamav
 from repro.configs.whisper_small import CONFIG as _whisper
 
 ARCHS = {c.name: c for c in (
-    _dsv3, _olmoe, _zamba2, _mistral, _ds7b,
+    _dsv3, _dsv3_ep32, _olmoe, _zamba2, _mistral, _ds7b,
     _nemotron, _chatglm, _rwkv, _llamav, _whisper)}
 
 

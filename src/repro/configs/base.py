@@ -19,15 +19,40 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                       # published routed experts: the router's width
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
     first_dense_layers: int = 0          # leading dense layers (deepseek-v3: 3)
     d_ff_dense: int = 0                  # ff dim of those dense layers
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25        # ep_tp rows a shard, ep_a2a send buffers; one device drops nothing
     router_aux_weight: float = 1e-2
-    impl: str = "dense"                  # "dense" (GShard einsum) | "ep_tp" (expert//model psum)
+    impl: str = "dense"                  # "dense" (no exchange) | "ep_tp" (experts over model, psum) | "ep_a2a" (experts over model x data, all-to-all)
+    # the experts this chip's share holds: n_held from first_held (0: all)
+    n_held: int = 0
+    first_held: int = 0
+    # router, by the published keys
+    scoring: str = "softmax"             # softmax | sigmoid (deepseek-v3)
+    n_group: int = 1                     # expert groups; the top topk_group groups are kept
+    topk_group: int = 1
+    norm_topk_prob: bool = True          # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V3 publishes it:
+    the `rope_scaling` keys of its config.json."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -94,6 +119,7 @@ class ModelConfig:
     rope_kind: str = "full"              # full | partial | 2d | none
     rope_fraction: float = 1.0
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     # mlp
     mlp_kind: str = "swiglu"             # swiglu | relu2 | gelu
     # optional components
@@ -149,10 +175,16 @@ class ModelConfig:
             vocab_size=over.pop("vocab_size", 256),
         )
         if self.moe is not None:
+            m = self.moe
+            n_exp = over.pop("n_experts", 8)
+            n_group = min(m.n_group, 4)
             kw["moe"] = replace(
-                self.moe, n_experts=over.pop("n_experts", 8), top_k=2,
-                d_ff_expert=64, first_dense_layers=min(self.moe.first_dense_layers, 1),
-                d_ff_dense=96)
+                m, n_experts=n_exp, top_k=2,
+                d_ff_expert=64, first_dense_layers=min(m.first_dense_layers, 1),
+                d_ff_dense=96, n_group=n_group,
+                topk_group=max(1, min(m.topk_group, n_group // 2)),
+                # a share keeps being a share: half the experts, from 0
+                n_held=n_exp // 2 if m.n_held else 0, first_held=0)
         if self.mla is not None:
             kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
                                   qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)

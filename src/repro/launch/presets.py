@@ -9,18 +9,33 @@ perf hillclimb starts from. Tuned for v5e (16 GiB HBM/chip):
 * expert FSDP for deepseek-v3 (652B expert params need sharding over both
   axes: 256 experts / 16 model-shards x ff/16 over data);
 * decode/prefill run microbatches=1 and keep ZeRO off (no optimizer).
+
+A model is big by its published depth, not by the depth it runs at: a
+registered architecture cut to fewer layers (one pipeline stage of it)
+keeps the storage its whole model gets.
 """
 from __future__ import annotations
 
+import dataclasses
+
+from repro.configs import ARCHS
 from repro.configs.base import MeshConfig, ModelConfig, RunConfig, ShapeConfig
 
 _BIG_PARAMS = 30e9
 
 
+def published_params(cfg: ModelConfig) -> int:
+    """Parameters of `cfg` at the depth its registered architecture
+    publishes (its own depth when it is not registered)."""
+    arch = ARCHS.get(cfg.name)
+    if arch is not None and arch.n_layers != cfg.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=arch.n_layers)
+    return cfg.param_count()
+
+
 def preset_run(cfg: ModelConfig, shape: ShapeConfig,
                mesh_cfg: MeshConfig) -> RunConfig:
-    n_params = cfg.param_count()
-    big = n_params >= _BIG_PARAMS
+    big = published_params(cfg) >= _BIG_PARAMS
     run = RunConfig(
         attn_impl="blocked",
         remat="boundaries",

@@ -321,8 +321,10 @@ def _project_qkv(params, x, cfg: ModelConfig, positions):
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(x.dtype))
-    q = L.rotary(q, positions, cfg.rope_kind, cfg.rope_fraction, cfg.rope_theta)
-    k = L.rotary(k, positions, cfg.rope_kind, cfg.rope_fraction, cfg.rope_theta)
+    q = L.rotary(q, positions, cfg.rope_kind, cfg.rope_fraction, cfg.rope_theta,
+                 cfg.rope_scaling)
+    k = L.rotary(k, positions, cfg.rope_kind, cfg.rope_fraction, cfg.rope_theta,
+                 cfg.rope_scaling)
     return q, k, v
 
 
@@ -377,7 +379,8 @@ def mla_prefill(params, x, cfg: ModelConfig, run: RunConfig, *,
     if positions is None:
         positions = jnp.arange(S)
     out = mla(params, x, cfg, run, positions=positions, causal=True)
-    ckv, kr = _mla_latent(params, x, cfg, positions)
+    with jax.named_scope("mla"):
+        ckv, kr = _mla_latent(params, x, cfg, positions)
     if pad_to > S:
         ckv = jnp.pad(ckv, ((0, 0), (0, pad_to - S), (0, 0)))
         kr = jnp.pad(kr, ((0, 0), (0, pad_to - S), (0, 0)))
@@ -496,6 +499,17 @@ def init_mla(key, cfg: ModelConfig):
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(q head dim), times YaRN's mscale(factor, mscale_all_dim)^2
+    where the rope is YaRN-scaled (DeepSeek-V3: 192^-0.5 x 1.874)."""
+    m = cfg.mla
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(params, x, cfg, positions):
     m = cfg.mla
     cq = jnp.einsum("bsd,dr->bsr", x, params["wdq"].astype(x.dtype))
@@ -503,7 +517,7 @@ def _mla_q(params, x, cfg, positions):
     q = jnp.einsum("bsr,rhk->bshk", cq, params["wuq"].astype(x.dtype))
     q_nope = q[..., :m.qk_nope_dim]
     q_rope = L.rotary(q[..., m.qk_nope_dim:], positions, "full", 1.0,
-                      cfg.rope_theta)
+                      cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -512,14 +526,20 @@ def _mla_latent(params, x, cfg, positions):
     ckv = L.rms_norm(ckv, params["kv_norm"], cfg.norm_eps)
     kr = jnp.einsum("bsd,dr->bsr", x, params["wkr"].astype(x.dtype))
     kr = L.rotary(kr[:, :, None, :], positions, "full", 1.0,
-                  cfg.rope_theta)[:, :, 0, :]
+                  cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return ckv, kr
 
 
 def mla(params, x, cfg: ModelConfig, run: RunConfig, *, positions=None,
         causal: bool = True):
     """MLA over a full sequence: expand latents to per-head K/V and run the
-    blocked softmax core with the combined (nope|rope) q/k."""
+    blocked softmax core with the combined (nope|rope) q/k. Traced under
+    the scope `mla`."""
+    with jax.named_scope("mla"):
+        return _mla(params, x, cfg, run, positions, causal)
+
+
+def _mla(params, x, cfg, run, positions, causal):
     m = cfg.mla
     B, S, _ = x.shape
     if positions is None:
@@ -529,12 +549,14 @@ def mla(params, x, cfg: ModelConfig, run: RunConfig, *, positions=None,
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv, params["wuk"].astype(x.dtype))
     v = jnp.einsum("bsr,rhk->bshk", ckv, params["wuv"].astype(x.dtype))
     H = cfg.n_heads
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    # the cores scale by 1/sqrt(q head dim); YaRN's mscale^2 goes on q
+    qk = m.qk_nope_dim + m.qk_rope_dim
+    q = jnp.concatenate([q_nope, q_rope], axis=-1) * \
+        (mla_softmax_scale(cfg) * math.sqrt(qk))
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(kr[:, :, None, :], (B, S, H, m.qk_rope_dim))],
         axis=-1)
     # pad v to qk dim so the shared core can be reused, then slice
-    qk = m.qk_nope_dim + m.qk_rope_dim
     if run.attn_impl == "full":
         o = full_attention(q, k, v if v.shape[-1] == qk else
                            jnp.pad(v, ((0, 0),) * 3 + ((0, qk - m.v_head_dim),)),
@@ -552,8 +574,13 @@ def mla(params, x, cfg: ModelConfig, run: RunConfig, *, positions=None,
 def mla_decode(params, x, cache, cfg: ModelConfig, run: RunConfig):
     """Absorbed-latent decode: cache only (c_kv, k_rope) = kv_lora+rope dims
     per token (DeepSeek-V3's memory saving), absorb wuk into q and wuv into
-    the output path. pos: (B,) per-row positions."""
-    m = cfg.mla
+    the output path. pos: (B,) per-row positions. Traced under the scope
+    `mla`."""
+    with jax.named_scope("mla"):
+        return _mla_decode(params, x, cache, cfg)
+
+
+def _mla_decode(params, x, cache, cfg):
     B = x.shape[0]
     pos = cache["pos"]                       # (B,)
     positions = pos[:, None]
@@ -567,7 +594,7 @@ def mla_decode(params, x, cache, cfg: ModelConfig, run: RunConfig):
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, params["wuk"].astype(x.dtype))
     s = (jnp.einsum("bshr,btr->bhst", q_lat, ckv.astype(x.dtype)) +
          jnp.einsum("bshk,btk->bhst", q_rope, kr.astype(x.dtype)))
-    s = s.astype(jnp.float32) / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    s = s.astype(jnp.float32) * mla_softmax_scale(cfg)
     s = jnp.where(jnp.arange(ckv.shape[1])[None, None, None, :] <=
                   pos[:, None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(x.dtype)

@@ -67,11 +67,55 @@ def layer_norm(x, scale, bias, eps: float = 1e-5):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(dim: int, theta: float, positions):
-    """(..., dim/2) angle table for given positions (any int array)."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor: 0.1 * mscale * ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                          theta: float, original_max: int):
+    """The rotary pair indices between which YaRN blends extrapolated and
+    interpolated frequencies: the dims that turn `beta_fast` and
+    `beta_slow` times over `original_max` positions."""
+    def dim_of(turns):
+        return dim * math.log(original_max / (turns * 2 * math.pi)) / \
+            (2 * math.log(theta))
+
+    low = math.floor(dim_of(beta_fast))
+    high = math.ceil(dim_of(beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None):
+    """(dim/2,) inverse frequencies; with `yarn` (a YarnConfig), each pair
+    blends the original frequency (below the correction range) with it
+    divided by `yarn.factor` (above it), linearly across the range."""
     inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn is None:
+        return inv
+    low, high = yarn_correction_range(yarn.beta_fast, yarn.beta_slow, dim,
+                                      theta,
+                                      yarn.original_max_position_embeddings)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) /
+                    (high - low), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_freqs(dim: int, theta: float, positions, yarn=None):
+    """(..., dim/2) cos and sin tables for given positions (any int
+    array); with `yarn`, YaRN's frequencies and its cos/sin scale,
+    mscale / mscale_all_dim."""
+    inv = rope_inv_freq(dim, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., dim/2)
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / \
+            yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def apply_rope(x, cos, sin):
@@ -85,8 +129,10 @@ def apply_rope(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def rotary(x, positions, kind: str, fraction: float, theta: float):
-    """Apply RoPE variant to (B, S, H, D) given positions (B, S) or (S,).
+def rotary(x, positions, kind: str, fraction: float, theta: float,
+           yarn=None):
+    """Apply RoPE variant to (B, S, H, D) given positions (B, S) or (S,),
+    with YaRN's scaling when `yarn` (a YarnConfig) is given.
 
     kind: "full"    — rotate all dims
           "partial" — rotate leading `fraction` of dims (nemotron)
@@ -103,7 +149,7 @@ def rotary(x, positions, kind: str, fraction: float, theta: float):
     rot = max(2, (rot // 2) * 2)
     if positions.ndim == 1:
         positions = positions[None, :]
-    cos, sin = rope_freqs(rot, theta, positions)      # (B, S, rot/2)
+    cos, sin = rope_freqs(rot, theta, positions, yarn)  # (B, S, rot/2)
     cos = cos[:, :, None, :]
     sin = sin[:, :, None, :]
     if rot == d:

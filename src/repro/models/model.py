@@ -280,22 +280,27 @@ class Model:
         return {"ckv": ckv, "kr": kr,
                 "pos": jnp.broadcast_to(pos_scalar, (n, B)).copy()}
 
-    def decode_step(self, params, batch, caches, mesh=None):
-        """One token for every sequence in the batch -> (logits, caches)."""
+    def decode_step(self, params, batch, caches, mesh=None,
+                    routed: bool = False):
+        """One token for every sequence in the batch -> (logits, caches);
+        with `routed`, (logits, caches, routed), where for the moe family
+        `routed` holds the pairs each MoE layer routed to each held expert,
+        int32 (MoE layers, held), and is None for every other family."""
         cfg, run = self.cfg, self.run
         tokens = batch["tokens"]                     # (B, 1)
         x = self._embed(params, tokens, mesh)
+        counts = None                                # pairs to held experts
         if cfg.family == "dense":
-            x, caches = T.stack_decode(params["layers"], x, caches, cfg, run,
-                                       kind="dense", mesh=mesh)
+            x, caches, _ = T.stack_decode(params["layers"], x, caches, cfg,
+                                          run, kind="dense", mesh=mesh)
         elif cfg.family == "moe":
             n_dense = cfg.moe.first_dense_layers
             new = {}
             if n_dense:
-                x, new["dense"] = T.stack_decode(
+                x, new["dense"], _ = T.stack_decode(
                     params["dense_layers"], x, caches["dense"], cfg, run,
                     kind="dense", mesh=mesh)
-            x, new["moe"] = T.stack_decode(
+            x, new["moe"], counts = T.stack_decode(
                 params["layers"], x, caches["moe"], cfg, run, kind="moe",
                 mesh=mesh)
             caches = new
@@ -313,7 +318,8 @@ class Model:
             enc_out = batch["enc_out"].astype(self.compute_dtype)
             x, caches = T.encdec_decode(params["layers"], x, enc_out, caches,
                                         cfg, run)
-        return self._logits(params, x, mesh), caches
+        logits = self._logits(params, x, mesh)
+        return (logits, caches, counts) if routed else (logits, caches)
 
 
 def build_model(cfg: ModelConfig, run: Optional[RunConfig] = None) -> Model:

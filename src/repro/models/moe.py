@@ -1,27 +1,44 @@
-"""Mixture-of-Experts: top-k router + two execution paths.
+"""Mixture-of-Experts: one router and one held-experts layer, run on one
+device or inside the expert-parallel shard bodies.
 
-``dense``  — dropless reference: every expert runs over all tokens with a
-             gate mask. O(E * T * d * ff) — only for tests / tiny configs.
-``ep_tp``  — production path: experts sharded over the 'model' mesh axis
-             (expert parallelism folded into tensor parallelism). Activations
-             at the MoE input are replicated over 'model' (standard Megatron
-             layer boundary), so each model shard *already owns* every token:
-             dispatch is a purely local sort/gather into (E_local, C, d)
-             capacity buffers, expert FFNs run as batched local matmuls, and
-             the combine psum over 'model' replaces the row-parallel
-             all-reduce a dense MLP would need anyway — zero extra
-             collectives vs dense TP, and zero one-hot-einsum FLOPs (the
-             GShard dispatch einsum would cost ~E*C/(k*ff) times the useful
-             expert compute: 400x for 256-expert top-8 — see DESIGN.md).
+The router (`route`) scores every published expert (`MoEConfig.n_experts`,
+its width), whatever the device holds. The expert layer (`held_experts`)
+holds `MoEConfig.held` experts from `first_held` and adds, for each pair
+(token, expert) routed to one of them, the gated expert output; pairs
+routed elsewhere add nothing here. The pairs are sorted by held expert
+and run through `lax.ragged_dot`, so its matmul work follows the pairs
+routed to held experts. On one device it drops no pair at any load.
 
-Optionally (RunConfig.fsdp_experts) expert weights are stored sharded over
-'data' along the ff dim (ZeRO-3 style) and all-gathered transiently per
-layer inside the shard_map body.
+``dense``  — one device, no exchange (`moe_dense`): the router, the held
+             experts and the shared expert. With every expert held it is
+             the whole layer; with a share held it is the part that one
+             chip of an expert-parallel deployment computes.
+``ep_tp``  — experts sharded over the 'model' mesh axis (expert
+             parallelism folded into tensor parallelism). Activations at
+             the MoE input are replicated over 'model', so each model shard
+             already owns every token: it runs the held-experts layer on
+             its own experts over rows for `capacity_factor` times its
+             even share of the pairs (held pairs past them are dropped,
+             so a shard holds no (T*k, d) buffer), and the combine psum
+             over 'model' replaces
+             the row-parallel all-reduce a dense MLP would need anyway.
+             Optionally (RunConfig.fsdp_experts) expert weights are stored
+             sharded over 'data' along the ff dim and all-gathered per
+             layer inside the shard body.
+``ep_a2a`` — experts over (model x data), DeepSeek-style: tokens travel to
+             the data shard that holds their expert by all_to_all over
+             'data' (send buffers of `capacity_factor`), run through the
+             held-experts layer there, and come back the same way; shards
+             over 'model' combine by psum.
+
+Every path returns (out, aux, routed): the layer's output, the
+load-balance loss and the pairs routed to each held expert, int32
+(held,), summed over the batch. Traced under the scopes `moe.route` (the
+router) and `moe.experts` (the held experts and the shared expert).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +47,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig, RunConfig
 from repro.models import layers as L
+
+BIAS = "e_score_correction_bias"
 
 
 # ---------------------------------------------------------------------------
@@ -43,11 +62,13 @@ def init_moe(key, cfg: ModelConfig):
     ks = jax.random.split(key, 5)
     p = {
         "router": L.dense_init(ks[0], (d, m.n_experts)),
-        "w_gate": L.dense_init(ks[1], (m.n_experts, d, m.d_ff_expert)),
-        "w_up": L.dense_init(ks[2], (m.n_experts, d, m.d_ff_expert)),
-        "w_down": L.dense_init(ks[3], (m.n_experts, m.d_ff_expert, d),
+        "w_gate": L.dense_init(ks[1], (m.held, d, m.d_ff_expert)),
+        "w_up": L.dense_init(ks[2], (m.held, d, m.d_ff_expert)),
+        "w_down": L.dense_init(ks[3], (m.held, m.d_ff_expert, d),
                                in_axis_size=m.d_ff_expert),
     }
+    if m.scoring == "sigmoid":
+        p[BIAS] = jnp.zeros((m.n_experts,))
     if m.n_shared_experts:
         p["shared"] = L.init_mlp(
             ks[4], d, m.d_ff_expert * m.n_shared_experts, "swiglu")
@@ -59,105 +80,148 @@ def init_moe(key, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def route(router_w, x, m: MoEConfig):
-    """x: (T, d) -> gates (T, k) normalized, idx (T, k), aux load-balance loss.
+def route(router_w, x, m: MoEConfig, bias=None):
+    """x: (T, d) -> gates (T, k), idx (T, k) into all `m.n_experts`, aux.
 
-    Softmax router with top-k renormalization (OLMoE); the DeepSeek-V3
-    sigmoid+bias variant differs only in the score nonlinearity — the
-    balancing aux term below is the standard switch-style load loss.
+    Scores in float32: a softmax over the experts (OLMoE) or each
+    expert's sigmoid (DeepSeek-V3). Selection adds `bias` (DeepSeek-V3's
+    e_score_correction_bias) to the scores and, with `n_group` > 1, keeps
+    only the `topk_group` groups whose two best biased scores sum highest;
+    the `top_k` best of the experts left are chosen. A gate is its
+    expert's unbiased score; the k gates are renormalised to sum 1 when
+    `norm_topk_prob`, then scaled by `routed_scaling_factor`. `aux` is the
+    switch-style load loss over the scores normalised per token.
     """
-    logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, idx = lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
-    # aux: E * mean(frac_tokens_e * mean_prob_e)
-    E = m.n_experts
-    onehot = jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32)
-    frac = jnp.mean(onehot, axis=0)
-    mprob = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * mprob)
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            router_w.astype(jnp.float32))
+        if m.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        T, E = scores.shape
+        if m.n_group > 1:
+            per = E // m.n_group
+            grouped = choice.reshape(T, m.n_group, per)
+            best = lax.top_k(grouped, min(2, per))[0].sum(-1)
+            _, keep = lax.top_k(best, m.topk_group)
+            kept = jnp.zeros((T, m.n_group), bool).at[
+                jnp.arange(T)[:, None], keep].set(True)
+            choice = jnp.where(jnp.repeat(kept, per, axis=1), choice,
+                               -jnp.inf)
+        _, idx = lax.top_k(choice, m.top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        if m.norm_topk_prob:
+            gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
+        gates = gates * m.routed_scaling_factor
+        # aux: E * mean(frac_tokens_e * mean_prob_e)
+        probs = scores / jnp.sum(scores, -1, keepdims=True)
+        frac = jnp.mean(jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32), 0)
+        aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
     return gates.astype(x.dtype), idx, aux
 
 
+def routed_to(idx, first, n: int):
+    """Pairs of `idx` routed to each of experts [first, first + n): int32
+    (n,). `first` may be traced."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < n), local, n)
+    return jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
-# dense (dropless) reference path
+# the held-experts layer
+# ---------------------------------------------------------------------------
+
+
+def held_experts(xt, idx, gates, w_gate, w_up, w_down, first, rows=None):
+    """The held experts' part of the layer. xt: (T, d); idx, gates: (T, k)
+    from `route`; w_*: (E_held, ...) the experts [first, first + E_held),
+    `first` possibly traced (a shard's offset).
+
+    For every pair (t, j) with idx[t, j] held: gates[t, j] times the
+    expert's SwiGLU of xt[t], summed per token -> (T, d), and the pairs
+    routed to each held expert, int32 (E_held,). The pairs are sorted by
+    held expert, the others last, and each matmul is one `ragged_dot`
+    over the groups of held pairs. `rows` bounds the rows the matmuls
+    run over: with None, all T * k and no pair is dropped; with fewer,
+    the held pairs past the first `rows` are dropped.
+    """
+    with jax.named_scope("moe.experts"):
+        T, k = idx.shape
+        E = w_gate.shape[0]
+        dt = xt.dtype
+        local = idx.reshape(-1) - first
+        held = (local >= 0) & (local < E)
+        order = jnp.argsort(jnp.where(held, local, E), stable=True)
+        routed = routed_to(idx, first, E)
+        dropless = rows is None or rows >= T * k
+        if dropless:
+            take, sizes = order, routed
+        else:
+            take = order[:rows]
+            sizes = jnp.diff(jnp.minimum(jnp.cumsum(routed), rows),
+                             prepend=0)
+        xs = xt[take // k]                      # (rows, d) by held expert
+        g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
+        u = lax.ragged_dot(xs, w_up.astype(dt), sizes)
+        y = lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(dt), sizes)
+        # rows past the held groups are not the product of any expert
+        w = jnp.where(held, gates.reshape(-1), 0)[take]
+        y = jnp.where(held[take][:, None], y * w[:, None].astype(dt), 0)
+        if dropless:
+            # back to (token, pair) order; each token's k terms summed in f32
+            y = y[jnp.argsort(order)].reshape(T, k, -1)
+            out = jnp.sum(y.astype(jnp.float32), axis=1)
+        else:
+            out = jnp.zeros((T, y.shape[-1]), jnp.float32).at[
+                take // k].add(y.astype(jnp.float32))
+        out = out.astype(dt)
+    return out, routed
+
+
+def _shared(shared, x, mlp_kind: str = "swiglu"):
+    if not shared:
+        return 0
+    with jax.named_scope("moe.experts"):
+        return L.mlp(shared, x, mlp_kind)
+
+
+def _router(params):
+    return {k: params[k] for k in ("router", BIAS) if k in params}
+
+
+# ---------------------------------------------------------------------------
+# one device, no exchange
 # ---------------------------------------------------------------------------
 
 
 def moe_dense(params, x, cfg: ModelConfig):
-    """x: (B,S,d). Every expert processes all tokens; gate-masked combine."""
+    """x: (B,S,d). The router over every expert, the held experts' part and
+    the shared expert; nothing is exchanged."""
     m = cfg.moe
     B, S, d = x.shape
     xt = x.reshape(B * S, d)
-    gates, idx, aux = route(params["router"], xt, m)
-    # combine weights (T, E)
-    comb = jnp.zeros((B * S, m.n_experts), x.dtype)
-    t = jnp.arange(B * S)
-    for j in range(m.top_k):
-        comb = comb.at[t, idx[:, j]].add(gates[:, j])
-    g = jnp.einsum("td,edf->tef", xt, params["w_gate"].astype(x.dtype))
-    u = jnp.einsum("td,edf->tef", xt, params["w_up"].astype(x.dtype))
-    h = jax.nn.silu(g) * u
-    y = jnp.einsum("tef,efd->ted", h, params["w_down"].astype(x.dtype))
-    out = jnp.einsum("ted,te->td", y, comb)
-    out = out.reshape(B, S, d)
-    if m.n_shared_experts:
-        out = out + L.mlp(params["shared"], x, "swiglu")
-    return out, aux
+    gates, idx, aux = route(params["router"], xt, m, params.get(BIAS))
+    out, routed = held_experts(xt, idx, gates, params["w_gate"],
+                               params["w_up"], params["w_down"],
+                               m.first_held)
+    out = out.reshape(B, S, d) + _shared(params.get("shared"), x)
+    return out, aux, routed
 
 
 # ---------------------------------------------------------------------------
-# EP path: local sort/gather dispatch, experts over 'model'
+# EP path: experts over 'model'
 # ---------------------------------------------------------------------------
 
 
-def _local_expert_ffn(w_gate, w_up, w_down, xb):
-    """xb: (E_local, C, d) capacity buffers -> (E_local, C, d)."""
-    g = jnp.einsum("ecd,edf->ecf", xb, w_gate)
-    u = jnp.einsum("ecd,edf->ecf", xb, w_up)
-    h = jax.nn.silu(g) * u
-    return jnp.einsum("ecf,efd->ecd", h, w_down)
+def _batch_sum(v, axis_names):
+    batch = tuple(a for a in axis_names if a != "model")
+    return lax.psum(v, batch) if batch else v
 
 
-def _dispatch_local(xt, idx, gates, e_lo, E_local: int, C: int):
-    """Gather tokens assigned to experts [e_lo, e_lo+E_local) into capacity
-    buffers. xt: (T, d); idx/gates: (T, k); e_lo may be traced (axis_index).
-
-    Returns xb (E_l, C, d) token buffers, src (E_l, C) source-token index
-    (-1 = empty slot), w (E_l, C) gate weights. Sort-based: O(Tk log Tk)
-    dispatch with *no* one-hot einsum FLOPs. Scatters use .add so that the
-    masked-out entries (which all target slot (0,0) with value 0) can never
-    clobber a real token.
-    """
-    T, k = idx.shape
-    flat_e = idx.reshape(-1)                       # (T*k,)
-    flat_g = gates.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    le = flat_e - e_lo                             # local expert id
-    is_local = (le >= 0) & (le < E_local)
-    le_key = jnp.where(is_local, le, E_local)      # sentinel sorts last
-    order = jnp.argsort(le_key, stable=True)
-    le_s = le_key[order]
-    t_s = flat_t[order]
-    g_s = flat_g[order]
-    counts = jnp.bincount(le_key, length=E_local + 1)[:E_local]
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
-    pos = jnp.arange(T * k)
-    rank = pos - starts[jnp.clip(le_s, 0, E_local - 1)]
-    valid = (le_s < E_local) & (rank < C)
-    be = jnp.where(valid, le_s, 0)
-    br = jnp.where(valid, rank, 0)
-    xb = jnp.zeros((E_local, C, xt.shape[1]), xt.dtype).at[be, br].add(
-        jnp.where(valid[:, None], xt[t_s], 0))
-    w = jnp.zeros((E_local, C), gates.dtype).at[be, br].add(
-        jnp.where(valid, g_s, 0))
-    src = (jnp.zeros((E_local, C), jnp.int32).at[be, br].add(
-        jnp.where(valid, t_s + 1, 0)) - 1)
-    return xb, src, w
-
-
-def _moe_ep_body(x, router_w, w_gate, w_up, w_down, shared, *,
+def _moe_ep_body(x, router, w_gate, w_up, w_down, shared, *,
                  m: MoEConfig, fsdp: bool, axis_names=("data", "model"),
                  mlp_kind: str = "swiglu"):
     """shard_map body. x: (B_l, S, d) local batch shard, replicated over
@@ -168,28 +232,18 @@ def _moe_ep_body(x, router_w, w_gate, w_up, w_down, shared, *,
         w_down = lax.all_gather(w_down, "data", axis=1, tiled=True)
     B_l, S, d = x.shape
     xt = x.reshape(B_l * S, d)
-    gates, idx, aux = route(router_w, xt, m)
+    gates, idx, aux = route(router["router"], xt, m, router.get(BIAS))
     E_local = w_gate.shape[0]
-    shard = lax.axis_index("model")
-    e_lo = shard * E_local
-    T = B_l * S
-    C = max(1, int(T * m.top_k * m.capacity_factor / m.n_experts))
-    xb, src, w = _dispatch_local(xt, idx, gates, e_lo, E_local, C)
-    yb = _local_expert_ffn(w_gate.astype(x.dtype), w_up.astype(x.dtype),
-                           w_down.astype(x.dtype), xb)
-    # combine: scatter-add back to token buffer, weighted
-    out = jnp.zeros((T, d), x.dtype)
-    flat_src = src.reshape(-1)
-    flat_y = (yb * w[..., None].astype(yb.dtype)).reshape(-1, d)
-    ok = flat_src >= 0
-    out = out.at[jnp.where(ok, flat_src, 0)].add(
-        jnp.where(ok[:, None], flat_y, 0))
+    first = m.first_held + lax.axis_index("model") * E_local
+    # rows for capacity_factor times this shard's even share of the pairs
+    rows = E_local * max(1, int(B_l * S * m.top_k * m.capacity_factor /
+                                m.n_experts))
+    out, _ = held_experts(xt, idx, gates, w_gate, w_up, w_down, first, rows)
     out = lax.psum(out, "model")
     aux = lax.pmean(aux, tuple(axis_names))   # replicated scalar
-    out = out.reshape(B_l, S, d)
-    if shared:
-        out = out + L.mlp(shared, x, mlp_kind)
-    return out, aux
+    routed = _batch_sum(routed_to(idx, m.first_held, m.held), axis_names)
+    out = out.reshape(B_l, S, d) + _shared(shared, x, mlp_kind)
+    return out, aux, routed
 
 
 def moe_ep(params, x, cfg: ModelConfig, run: RunConfig, mesh):
@@ -203,12 +257,12 @@ def moe_ep(params, x, cfg: ModelConfig, run: RunConfig, mesh):
     shared = params.get("shared", {})
     fn = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(xspec, P(None, None),
+        in_specs=(xspec, P(),
                   P("model", None, ff_spec), P("model", None, ff_spec),
                   P("model", ff_spec, None), P()),
-        out_specs=(xspec, P()),
+        out_specs=(xspec, P(), P()),
         check_vma=False)
-    return fn(x, params["router"], params["w_gate"], params["w_up"],
+    return fn(x, _router(params), params["w_gate"], params["w_up"],
               params["w_down"], shared)
 
 
@@ -223,22 +277,22 @@ def moe_ep(params, x, cfg: ModelConfig, run: RunConfig, mesh):
 # ---------------------------------------------------------------------------
 
 
-def _moe_ep_a2a_body(x, router_w, w_gate, w_up, w_down, shared, *,
+def _moe_ep_a2a_body(x, router, w_gate, w_up, w_down, shared, *,
                      m: MoEConfig, axis_names, data_axis="data",
                      mlp_kind: str = "swiglu"):
     B_l, S, d = x.shape
     xt = x.reshape(B_l * S, d)
     T = B_l * S
-    gates, idx, aux = route(router_w, xt, m)
+    gates, idx, aux = route(router["router"], xt, m, router.get(BIAS))
     E_local = w_gate.shape[0]                 # experts on THIS device
     M = lax.axis_size("model")
     D = lax.axis_size(data_axis)
     m_idx = lax.axis_index("model")
-    # expert e lives on (m = e // (D*E_local), d = (e // E_local) % D)
-    # this m-shard only handles its own experts; others contribute via the
-    # final psum over 'model'
+    # expert first_held + e lives on (m = e // (D*E_local),
+    # d = (e // E_local) % D); this m-shard only handles its own experts,
+    # others contribute via the final psum over 'model'
     per_m = D * E_local
-    e_lo_m = m_idx * per_m
+    e_lo_m = m.first_held + m_idx * per_m
     le = idx - e_lo_m                          # (T, k) local-to-m expert id
     mine = (le >= 0) & (le < per_m)
     owner_d = jnp.where(mine, le // E_local, D)     # D = sentinel
@@ -251,7 +305,7 @@ def _moe_ep_a2a_body(x, router_w, w_gate, w_up, w_down, shared, *,
     flat_g = gates.reshape(-1)
     flat_dst = owner_d.reshape(-1)
     flat_slot = slot.reshape(-1)
-    # rank within destination bucket (sort-based, as in _dispatch_local)
+    # rank within destination bucket (sort-based)
     order = jnp.argsort(jnp.where(flat_dst < D, flat_dst, D), stable=True)
     dst_s = flat_dst[order]
     t_s = flat_t[order]
@@ -273,35 +327,16 @@ def _moe_ep_a2a_body(x, router_w, w_gate, w_up, w_down, shared, *,
     # exchange: every shard sends bucket j to data-shard j
     recv_x = lax.all_to_all(send_x, data_axis, 0, 0, tiled=False)
     recv_meta = lax.all_to_all(send_meta, data_axis, 0, 0, tiled=False)
-    # recv_*: (D, C_send, ...) — tokens from every source shard
+    # recv_*: (D, C_send, ...) — one pair per row from every source shard,
+    # for local expert `rslot`; empty rows route to no held expert
     rx = recv_x.reshape(D * C_send, d)
-    rsrc = recv_meta[..., 0].reshape(-1).astype(jnp.int32) - 1  # -1 = empty
+    ok = recv_meta[..., 0].reshape(-1) > 0
     rslot = recv_meta[..., 1].reshape(-1).astype(jnp.int32)
-    ok = rsrc >= 0
-    # gather into per-local-expert capacity buffers (slack is already in
-    # C_send via capacity_factor)
-    C_loc = max(1, (D * C_send) // max(E_local, 1))
-    C_loc = min(C_loc, D * C_send)
-    key = jnp.where(ok, rslot, E_local)
-    order2 = jnp.argsort(key, stable=True)
-    k_s = key[order2]
-    counts2 = jnp.bincount(k_s, length=E_local + 1)[:E_local]
-    starts2 = jnp.concatenate(
-        [jnp.zeros((1,), counts2.dtype), jnp.cumsum(counts2)[:-1]])
-    rank2 = jnp.arange(D * C_send) - starts2[jnp.clip(k_s, 0, E_local - 1)]
-    valid2 = (k_s < E_local) & (rank2 < C_loc)
-    be = jnp.where(valid2, k_s, 0)
-    br2 = jnp.where(valid2, rank2, 0)
-    xb = jnp.zeros((E_local, C_loc, d), xt.dtype).at[be, br2].add(
-        jnp.where(valid2[:, None], rx[order2], 0))
-    yb = _local_expert_ffn(w_gate.astype(x.dtype), w_up.astype(x.dtype),
-                           w_down.astype(x.dtype), xb)
-    # scatter expert outputs back to the recv layout, then reverse a2a
-    y_flat = jnp.zeros((D * C_send, d), x.dtype).at[
-        jnp.where(valid2, order2, 0)].add(
-        jnp.where(valid2[:, None], yb[be, br2], 0))
-    y_send = y_flat.reshape(D, C_send, d)
-    y_back = lax.all_to_all(y_send, data_axis, 0, 0, tiled=False)
+    y_flat, _ = held_experts(
+        rx, jnp.where(ok, rslot, E_local)[:, None],
+        jnp.ones((D * C_send, 1), x.dtype), w_gate, w_up, w_down, 0)
+    y_back = lax.all_to_all(y_flat.reshape(D, C_send, d), data_axis, 0, 0,
+                            tiled=False)
     # combine at source: weight by gate, scatter-add per token
     out = jnp.zeros((T, d), x.dtype)
     yb_flat = y_back.reshape(-1, d)
@@ -311,10 +346,9 @@ def _moe_ep_a2a_body(x, router_w, w_gate, w_up, w_down, shared, *,
                    jnp.where(valid, g_s, 0)[:, None].astype(x.dtype)), 0))
     out = lax.psum(out, "model")
     aux = lax.pmean(aux, tuple(axis_names))
-    out = out.reshape(B_l, S, d)
-    if shared:
-        out = out + L.mlp(shared, x, mlp_kind)
-    return out, aux
+    routed = _batch_sum(routed_to(idx, m.first_held, m.held), axis_names)
+    out = out.reshape(B_l, S, d) + _shared(shared, x, mlp_kind)
+    return out, aux, routed
 
 
 def moe_ep_a2a(params, x, cfg: ModelConfig, run: RunConfig, mesh):
@@ -327,15 +361,17 @@ def moe_ep_a2a(params, x, cfg: ModelConfig, run: RunConfig, mesh):
     espec = P(("model", "data"), None, None)
     fn = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(xspec, P(None, None), espec, espec,
+        in_specs=(xspec, P(), espec, espec,
                   P(("model", "data"), None, None), P()),
-        out_specs=(xspec, P()),
+        out_specs=(xspec, P(), P()),
         check_vma=False)
-    return fn(x, params["router"], params["w_gate"], params["w_up"],
+    return fn(x, _router(params), params["w_gate"], params["w_up"],
               params["w_down"], shared)
 
 
 def moe(params, x, cfg: ModelConfig, run: RunConfig, mesh=None):
+    """(out, aux, routed) by `cfg.moe.impl` on `mesh`; with no mesh, or
+    one without a 'model' axis, the layer runs with no exchange."""
     if mesh is not None and "model" in mesh.axis_names:
         if cfg.moe.impl == "ep_a2a":
             return moe_ep_a2a(params, x, cfg, run, mesh)

@@ -81,7 +81,7 @@ def block(params, x, cfg: ModelConfig, run: RunConfig, *, kind="dense",
     h = L.rms_norm(x, params["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if kind == "moe":
-        h, aux = M.moe(params["moe"], h, cfg, run, mesh)
+        h, aux, _ = M.moe(params["moe"], h, cfg, run, mesh)
     else:
         h = L.mlp(params["mlp"], h, cfg.mlp_kind)
     return x + h, aux
@@ -89,7 +89,8 @@ def block(params, x, cfg: ModelConfig, run: RunConfig, *, kind="dense",
 
 def block_decode(params, x, cache, cfg: ModelConfig, run: RunConfig, *,
                  kind="dense", mesh=None, media_kv=None):
-    """One-token decode through a block; returns (x, new_cache)."""
+    """One-token decode through a block; returns (x, new_cache, routed):
+    the pairs routed to each held expert for a MoE block, else None."""
     h = L.rms_norm(x, params["ln1"], cfg.norm_eps)
     if kind == "cross":
         h = A.cross_attn(params["attn"], h, media_kv, run)
@@ -100,11 +101,12 @@ def block_decode(params, x, cache, cfg: ModelConfig, run: RunConfig, *,
         h, new_cache = A.gqa_decode(params["attn"], h, cache, cfg, run)
     x = x + h
     h = L.rms_norm(x, params["ln2"], cfg.norm_eps)
+    routed = None
     if kind == "moe":
-        h, _ = M.moe(params["moe"], h, cfg, run, mesh)
+        h, _, routed = M.moe(params["moe"], h, cfg, run, mesh)
     else:
         h = L.mlp(params["mlp"], h, cfg.mlp_kind)
-    return x + h, new_cache
+    return x + h, new_cache, routed
 
 
 # ---------------------------------------------------------------------------
@@ -144,15 +146,18 @@ def stack(params, x, cfg, run, *, kind="dense", mesh=None, positions=None,
 def stack_decode(params, x, caches, cfg, run, *, kind="dense", mesh=None,
                  media_kv=None):
     """Scan one token through a stacked group, threading per-layer caches.
-    caches: pytree stacked on axis 0."""
+    caches: pytree stacked on axis 0. Returns (x, new caches, routed): the
+    pairs routed to each held expert by layer, (n, held), for MoE blocks,
+    else None."""
     def body(carry, inp):
         layer_params, cache = inp
-        h, new_cache = block_decode(layer_params, carry, cache, cfg, run,
-                                    kind=kind, mesh=mesh, media_kv=media_kv)
-        return h, new_cache
+        h, new_cache, routed = block_decode(
+            layer_params, carry, cache, cfg, run, kind=kind, mesh=mesh,
+            media_kv=media_kv)
+        return h, (new_cache, routed)
 
-    x, new_caches = lax.scan(body, x, (params, caches))
-    return x, new_caches
+    x, (new_caches, routed) = lax.scan(body, x, (params, caches))
+    return x, new_caches, routed
 
 
 def block_prefill(params, x, cfg: ModelConfig, run: RunConfig, *,
@@ -168,7 +173,7 @@ def block_prefill(params, x, cfg: ModelConfig, run: RunConfig, *,
     x = x + h
     h = L.rms_norm(x, params["ln2"], cfg.norm_eps)
     if kind == "moe":
-        h, _ = M.moe(params["moe"], h, cfg, run, mesh)
+        h, _, _ = M.moe(params["moe"], h, cfg, run, mesh)
     else:
         h = L.mlp(params["mlp"], h, cfg.mlp_kind)
     return x + h, kv
@@ -299,7 +304,7 @@ def hybrid_stack_decode(params, x, caches, cfg, run):
         sel = jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, g % n_sets, 0, False),
             params["shared"])
-        h, new_ac = block_decode(sel, h, acache, cfg, run, kind="dense")
+        h, new_ac, _ = block_decode(sel, h, acache, cfg, run, kind="dense")
         return h, (new_mc, new_ac)
 
     x, (new_m, new_a) = lax.scan(
@@ -359,13 +364,13 @@ def vlm_stack_decode(params, x, media, caches, cfg, run):
 
         def s_body(c, inp2):
             lp, sc = inp2
-            y, nsc = block_decode(lp, c, sc, cfg, run, kind="dense")
+            y, nsc, _ = block_decode(lp, c, sc, cfg, run, kind="dense")
             return y, nsc
 
         h, new_sc = lax.scan(s_body, h, (selfp_g, scache_g))
         kv = A.cross_attn_kv(crossp["attn"], media)
-        h, _ = block_decode(crossp, h, None, cfg, run, kind="cross",
-                            media_kv=kv)
+        h, _, _ = block_decode(crossp, h, None, cfg, run, kind="cross",
+                               media_kv=kv)
         return h, new_sc
 
     x, new_caches = lax.scan(
@@ -423,7 +428,7 @@ def encdec_decode(params, x, enc_out, caches, cfg, run):
     def body(carry, inp):
         lp, cache = inp
         base = {k: lp[k] for k in ("ln1", "ln2", "attn", "mlp")}
-        h, nc = block_decode(base, carry, cache, cfg, run, kind="dense")
+        h, nc, _ = block_decode(base, carry, cache, cfg, run, kind="dense")
         kv = A.cross_attn_kv(lp["cross"], enc_out)
         c = L.rms_norm(h, lp["ln_cross"], cfg.norm_eps)
         h = h + A.cross_attn(lp["cross"], c, kv, run, gated=False)

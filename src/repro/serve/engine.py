@@ -19,6 +19,12 @@ appended) with the children `engine.admit` (admission and slot resets),
 `engine.fetch` (the logits to the host: where the host waits on the
 device) and `engine.sample` (the per-slot loop). The step is jitted as
 `decode_step`, so profiler traces name its program `jit_decode_step`.
+For a MoE model the step also returns, and `engine.fetch` copies beside
+the logits, the pairs each MoE layer routed to each expert this model
+holds (int32, MoE layers x held experts); the tick's span then carries
+`held_routes` (pairs routed to held experts, summed over the layers) and
+`experts_hit` (held experts with at least one pair, summed over the
+layers). Other families fetch the logits alone.
 
 Weights: the engine holds only the serving form of the tree it is given
 (`repro.serve.prepare`): every leaf the step reads only through a
@@ -88,8 +94,11 @@ class ServeEngine:
         if allocator is not None:
             self.attach_allocator(allocator)
 
+        self._routed = model.cfg.family == "moe"
+
         def decode_step(params, batch, caches):
-            return model.decode_step(params, batch, caches, None)
+            return model.decode_step(params, batch, caches, None,
+                                     routed=self._routed)
 
         self._step = jax.jit(decode_step)
         self.params = params
@@ -147,19 +156,26 @@ class ServeEngine:
             with span_if(on, "engine.admit"):
                 self._admit()
             slots = sum(r is not None for r in self.active)
-            tokens = self._step_slots(on) if slots else 0
+            tokens, routed = self._step_slots(on) if slots else (0, None)
             if sp is not None:
                 sp.attrs["slots"] = slots
                 sp.attrs["tokens"] = tokens
+                if routed is not None:
+                    sp.attrs["held_routes"] = int(routed.sum())
+                    sp.attrs["experts_hit"] = int((routed > 0).sum())
 
-    def _step_slots(self, on: bool) -> int:
+    def _step_slots(self, on: bool):
         """One batched step over the active slots; returns the output
-        tokens appended."""
+        tokens appended and, for a MoE model, the pairs routed to each held
+        expert by layer (else None)."""
         with span_if(on, "engine.dispatch"):
-            logits, self.caches = self._step(self.params, self._batch(),
-                                             self.caches)
+            out = self._step(self.params, self._batch(), self.caches)
+            logits, self.caches = out[0], out[1]
+        routed = None
         with span_if(on, "engine.fetch"):
             logits = np.asarray(logits[:, 0])       # (slots, V)
+            if self._routed:
+                routed = np.asarray(out[2])
         tokens = 0
         with span_if(on, "engine.sample"):
             for i, req in enumerate(self.active):
@@ -179,7 +195,7 @@ class ServeEngine:
                     req.finished_at = time.monotonic()
                     self.finished.append(req)
                     self.active[i] = None
-        return tokens
+        return tokens, routed
 
     def _admit(self):
         for i in range(self.slots):
@@ -212,9 +228,12 @@ class ServeEngine:
                                           temperature))
 
 
-# base rank of each cache leaf kind; batch axis = ndim - base_rank
-_BATCH_RANK = {"k": 4, "v": 4, "ckv": 3, "kr": 3, "pos": 1,
-               "h": 4, "conv": 3, "wkv": 4, "tm_last": 2, "cm_last": 2}
+# the cache leaves a new sequence must not inherit, by base rank (batch
+# axis = ndim - base rank): the per-row position and the recurrent states.
+# Attention keys and values (k, v, ckv, kr and their int8 scales) keep
+# their stale rows: every attention masks the positions past `pos`.
+_RESET_RANK = {"pos": 1, "h": 4, "conv": 3, "wkv": 4, "tm_last": 2,
+               "cm_last": 2}
 
 
 class AllocationEndpoint:
@@ -354,7 +373,8 @@ class AllocationEndpoint:
 def _reset_slot(caches, slot: int):
     """Zero one slot's state across all (stacked) cache leaves: per-row
     `pos` goes to 0 so stale KV beyond it is never attended; recurrent
-    states are cleared explicitly."""
+    states are cleared explicitly. The KV leaves are left as they are, so
+    an admission copies no cache."""
     def one(path, leaf):
         name = ""
         for p in reversed(path):
@@ -362,7 +382,7 @@ def _reset_slot(caches, slot: int):
             if isinstance(k, str):
                 name = k
                 break
-        rank = _BATCH_RANK.get(name)
+        rank = _RESET_RANK.get(name)
         if rank is None or leaf.ndim < rank:
             return leaf
         axis = leaf.ndim - rank

@@ -86,11 +86,11 @@ def test_moe_ep_sharded_matches_dense():
         run = RunConfig(compute_dtype='float32')
         params = M.init_moe(jax.random.PRNGKey(0), cfg)
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-        dense, aux_d = M.moe_dense(params, x, cfg)
+        dense, aux_d, _ = M.moe_dense(params, x, cfg)
         mesh = make_mesh((2, 4), ('data', 'model'))
         cfg_hi = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=float(cfg.moe.n_experts)))
-        ep, aux_e = jax.jit(lambda p, x: M.moe_ep(p, x, cfg_hi, run, mesh))(
+        ep, aux_e, _ = jax.jit(lambda p, x: M.moe_ep(p, x, cfg_hi, run, mesh))(
             params, x)
         err = float(jnp.max(jnp.abs(dense - ep)))
         print('ERR', err)
@@ -113,12 +113,12 @@ def test_moe_ep_a2a_matches_dense():
         params = M.init_moe(jax.random.PRNGKey(0), cfg)
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1),
                                     (4, 16, cfg.d_model))
-        dense, _ = M.moe_dense(params, x, cfg)
+        dense, _, _ = M.moe_dense(params, x, cfg)
         mesh = make_mesh((2, 4), ('data', 'model'))
         cfg_hi = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=float(cfg.moe.n_experts * 4),
             impl='ep_a2a'))
-        ep, _ = jax.jit(lambda p, x: M.moe_ep_a2a(p, x, cfg_hi, run, mesh))(
+        ep, _, _ = jax.jit(lambda p, x: M.moe_ep_a2a(p, x, cfg_hi, run, mesh))(
             params, x)
         err = float(jnp.max(jnp.abs(dense - ep)))
         assert err < 1e-4, err

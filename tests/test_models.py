@@ -104,6 +104,13 @@ def test_full_configs_match_spec():
         (61, 7168, 128, 129280)
     assert c.moe.n_experts == 256 and c.moe.top_k == 8
     assert c.mla.kv_lora_rank == 512
+    assert (c.moe.scoring, c.moe.n_group, c.moe.topk_group,
+            c.moe.norm_topk_prob, c.moe.routed_scaling_factor) == \
+        ("sigmoid", 8, 4, True, 2.5)
+    assert c.norm_eps == 1e-6
+    y = c.rope_scaling
+    assert (y.factor, y.original_max_position_embeddings, y.beta_fast,
+            y.beta_slow, y.mscale, y.mscale_all_dim) == (40, 4096, 32, 1, 1, 1)
     c = ARCHS["mistral-large-123b"]
     assert (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads, c.d_ff,
             c.vocab_size) == (88, 12288, 96, 8, 28672, 32768)
@@ -208,11 +215,13 @@ def test_moe_dense_vs_ep_capacity():
     cfg = ARCHS["olmoe-1b-7b"].reduced()
     params = M.init_moe(KEY, cfg)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(4), (2, 16, cfg.d_model))
-    dense_out, aux_d = M.moe_dense(params, x, cfg)
+    dense_out, aux_d, routed_d = M.moe_dense(params, x, cfg)
     mesh = make_mesh((1, 1), ("data", "model"))
     import dataclasses
     cfg_hi = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))
-    ep_out, aux_e = M.moe_ep(params, x, cfg_hi, RUN32, mesh)
+    ep_out, aux_e, routed_e = M.moe_ep(params, x, cfg_hi, RUN32, mesh)
     np.testing.assert_allclose(ep_out, dense_out, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(aux_d, aux_e, atol=1e-5)
+    np.testing.assert_array_equal(routed_e, routed_d)
+    assert int(routed_d.sum()) == 2 * 16 * cfg.moe.top_k

@@ -96,6 +96,43 @@ def test_engine_tick_tree(small_model, ring):
                    for t in ticks[1:] for s in walk(t))
 
 
+def test_engine_tick_carries_routing_for_moe_only(small_model, ring):
+    """A MoE model's step also returns the pairs each MoE layer routed to
+    each held expert; `engine.fetch` copies them beside the logits and the
+    tick records `held_routes` and `experts_hit`. A dense model's step
+    returns its logits and caches alone, as before, and its ticks carry
+    neither attribute."""
+    cfg = get_arch("deepseek-v3-671b-ep32").reduced()   # 4 of 8 held, top-2
+    m = build_model(cfg, RUN)
+    eng = ServeEngine(m, m.init(jax.random.PRNGKey(0)), slots=2, max_len=32)
+    for rid in range(3):
+        eng.submit(Request(rid, prompt=[rid + 1, 2, 3], max_new_tokens=4))
+    eng.run()
+    ticks = [r for r in ring.traces() if r.name == "engine.tick"]
+    n_moe = cfg.n_layers - cfg.moe.first_dense_layers
+    held = cfg.moe.held
+    for t in ticks:
+        # every slot of the batch is routed, active or not
+        assert 0 <= t.attrs["held_routes"] <= 2 * cfg.moe.top_k * n_moe
+        assert 0 <= t.attrs["experts_hit"] <= min(
+            held, 2 * cfg.moe.top_k) * n_moe
+        assert t.attrs["experts_hit"] <= t.attrs["held_routes"]
+    assert sum(t.attrs["held_routes"] for t in ticks) > 0
+    out = eng._step(eng.params, eng._batch(), eng.caches)
+    assert len(out) == 3 and out[2].shape == (n_moe, held)
+    assert out[2].dtype == jax.numpy.int32
+
+    dm, dp = small_model
+    dense = ServeEngine(dm, dp, slots=1, max_len=32)
+    n = len(ring)
+    dense.submit(Request(0, prompt=[4, 5], max_new_tokens=2))
+    dense.run()
+    dticks = [r for r in ring.traces()[n:] if r.name == "engine.tick"]
+    assert dticks and not any({"held_routes", "experts_hit"} & set(t.attrs)
+                              for t in dticks)
+    assert len(dense._step(dense.params, dense._batch(), dense.caches)) == 2
+
+
 def test_engine_spans_off_with_a_disabled_registry(small_model, ring):
     m, p = small_model
     eng = ServeEngine(m, p, slots=1, max_len=32,

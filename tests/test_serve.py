@@ -110,7 +110,7 @@ def test_serving_copy_matches_f32_step(arch):
     for t in range(1, len(prompts[0]) + 4):
         batch = {"tokens": jnp.asarray(tok)[:, None]}
         lg, new = f32(p, batch, caches)
-        lg_s, new_s = eng._step(eng.params, batch, caches)
+        lg_s, new_s = eng._step(eng.params, batch, caches)[:2]
         assert _same(lg, lg_s) and _same(new, new_s), t
         caches = new
         if t < len(prompts[0]):
@@ -124,6 +124,23 @@ def test_serving_copy_matches_f32_step(arch):
         eng.submit(Request(rid, prompt=pr, max_new_tokens=4))
     done = sorted(eng.run(), key=lambda r: r.rid)
     assert [r.out_tokens for r in done] == chain
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "deepseek-v3-671b-ep32",
+                                  "rwkv6-7b"])
+def test_a_reused_slot_serves_as_a_fresh_one(arch):
+    """A request admitted to a slot that served another before it gets
+    the tokens it gets alone: the slot's position and recurrent state
+    are reset, and its stale keys and values are never attended."""
+    m = build_model(get_arch(arch).reduced(), RUN_BF16)
+    p = m.init(jax.random.PRNGKey(3))
+    eng = ServeEngine(m, p, slots=1, max_len=32)
+    eng.submit(Request(0, prompt=[9, 4, 17, 2, 30, 8], max_new_tokens=7))
+    eng.submit(Request(1, prompt=[5, 11], max_new_tokens=5))
+    reused = eng.run()[1]
+    fresh = ServeEngine(m, p, slots=1, max_len=32)
+    fresh.submit(Request(1, prompt=[5, 11], max_new_tokens=5))
+    assert reused.out_tokens == fresh.run()[0].out_tokens
 
 
 def test_prepare_casts_matmul_weights_only(monkeypatch):
