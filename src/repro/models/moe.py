@@ -2,12 +2,32 @@
 device or inside the expert-parallel shard bodies.
 
 The router (`route`) scores every published expert (`MoEConfig.n_experts`,
-its width), whatever the device holds. The expert layer (`held_experts`)
-holds `MoEConfig.held` experts from `first_held` and adds, for each pair
+its width), whatever the device holds. The expert layer holds
+`MoEConfig.held` experts from `first_held` and adds, for each pair
 (token, expert) routed to one of them, the gated expert output; pairs
-routed elsewhere add nothing here. The pairs are sorted by held expert
-and run through `lax.ragged_dot`, so its matmul work follows the pairs
-routed to held experts. On one device it drops no pair at any load.
+routed elsewhere add nothing here. It has two forms, one algorithm with
+one numerics order (each expert's output rounded to the compute dtype,
+the gate applied after the down projection, the sum in float32):
+
+``held_experts``        the pairs sorted by held expert and run through
+                        `lax.ragged_dot`, so the matmul rows follow the
+                        pairs: bound by FLOPs where tokens are many, and
+                        it can bound its rows (`rows`) and drop the rest.
+                        XLA runs it as a custom call, which copies a
+                        weight operand that is a slice (a scan's layer).
+``held_experts_dense``  every token through every held expert, by dots
+                        batched over the expert on both operands, and the
+                        pairs not routed there weighted 0. Its FLOPs grow
+                        with tokens x held experts, but while the tokens
+                        are few it is bound by the weights' read, which
+                        is the least the layer can cost once every held
+                        expert has a pair; XLA fuses a scan's slice of
+                        the stacked weights into its dots.
+
+`moe_dense` takes the dense form where `experts_path` says it costs the
+weights' read alone (decode shapes on a chip of `PEAKS`), and the ragged
+form elsewhere; the shard bodies bound their rows and always run
+`held_experts`.
 
 ``dense``  — one device, no exchange (`moe_dense`): the router, the held
              experts and the shared expert. With every expert held it is
@@ -39,6 +59,7 @@ router) and `moe.experts` (the held experts and the shared expert).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +67,9 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig, RunConfig
+from repro.launch.roofline import PEAKS, ChipPeaks
 from repro.models import layers as L
+from repro.telemetry import default_registry
 
 BIAS = "e_score_correction_bias"
 
@@ -147,6 +170,13 @@ def held_experts(xt, idx, gates, w_gate, w_up, w_down, first, rows=None):
     over the groups of held pairs. `rows` bounds the rows the matmuls
     run over: with None, all T * k and no pair is dropped; with fewer,
     the held pairs past the first `rows` are dropped.
+
+    Its work follows the rows, so it is the form for many tokens, where
+    the layer is bound by FLOPs, for few pairs (a held expert no pair
+    reaches costs no read), and for a bound on rows. Where the weights
+    are a scan's slice of a stacked tree, XLA copies each slice before
+    the custom call, and with all T * k rows the dot runs over pairs that
+    are not held too: at decode shapes `held_experts_dense` costs less.
     """
     with jax.named_scope("moe.experts"):
         T, k = idx.shape
@@ -181,6 +211,66 @@ def held_experts(xt, idx, gates, w_gate, w_up, w_down, first, rows=None):
     return out, routed
 
 
+def held_experts_dense(xt, idx, gates, w_gate, w_up, w_down, first):
+    """`held_experts` with no bound on rows, by dots batched over the held
+    expert: every token through every held expert's SwiGLU, read from the
+    weights in their stored (E_held, d, f) / (E_held, f, d) layout, each
+    expert's output rounded to the compute dtype and weighted by the gate
+    of the pair that routed the token to that expert (0 where none did),
+    summed over the experts in float32. Same arguments and results.
+
+    It does n_experts / k times the FLOPs the held pairs need and reads
+    every held expert's weights, so it pays where the tokens are too few
+    for anything but that read to bound it (`experts_path`).
+    """
+    with jax.named_scope("moe.experts"):
+        T, k = idx.shape
+        E = w_gate.shape[0]
+        dt = xt.dtype
+        routed = routed_to(idx, first, E)
+        # (E, T): the gate of the pair that routed t to first + e, else 0
+        # (top-k picks k distinct experts, so at most one pair a cell)
+        hit = (idx - first)[None] == jnp.arange(E)[:, None, None]
+        w = jnp.sum(jnp.where(hit, gates[None], 0), axis=-1)
+        xe = jnp.broadcast_to(xt, (E,) + xt.shape)
+        g = jnp.einsum("etd,edf->etf", xe, w_gate.astype(dt))
+        u = jnp.einsum("etd,edf->etf", xe, w_up.astype(dt))
+        y = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                       w_down.astype(dt))
+        y = y * w[..., None].astype(dt)
+        out = jnp.sum(y.astype(jnp.float32), axis=0).astype(dt)
+    return out, routed
+
+
+def experts_path(T: int, top_k: int, n_experts: int, rows: Optional[int],
+                 peaks: Optional[ChipPeaks], itemsize: int) -> str:
+    """"dense" or "ragged": the form of the held-experts layer for T
+    tokens, each routed to `top_k` of `n_experts` experts, whose weights
+    are stored at `itemsize` bytes, on a chip of `peaks` (None: a kind
+    `PEAKS` lacks).
+
+    Dense only where it costs no more than the weights' read:
+    - no bound on rows (`rows` None): the dense form has none;
+    - every held expert expects a pair (T * top_k >= n_experts), else it
+      reads experts the ragged form skips;
+    - T at most the chip's ridge, itemsize * flops / (2 * hbm_bw) tokens
+      (about 240 for a v5e at bf16): each weight of `itemsize` bytes
+      meets all T tokens, 2 * T FLOPs, so up to the ridge the read
+      bounds the product.
+    How many experts are held cancels from both conditions.
+    """
+    if rows is not None or peaks is None or T * top_k < n_experts:
+        return "ragged"
+    ridge = itemsize * peaks.flops / (2 * peaks.hbm_bw)
+    return "dense" if T <= ridge else "ragged"
+
+
+def _device_peaks() -> Optional[ChipPeaks]:
+    """Peaks of the default backend's chip; None for a kind `PEAKS` lacks
+    (the host CPU among them)."""
+    return PEAKS.get(jax.devices()[0].device_kind)
+
+
 def _shared(shared, x, mlp_kind: str = "swiglu"):
     if not shared:
         return 0
@@ -199,14 +289,25 @@ def _router(params):
 
 def moe_dense(params, x, cfg: ModelConfig):
     """x: (B,S,d). The router over every expert, the held experts' part and
-    the shared expert; nothing is exchanged."""
+    the shared expert; nothing is exchanged.
+
+    The held experts run dense (`held_experts_dense`) where `experts_path`
+    finds the layer bound by their weights' read on this chip, ragged
+    (`held_experts`) elsewhere. The choice is fixed in the traced program:
+    each trace counts one to the process registry's counter
+    `moe.experts.dense` or `moe.experts.ragged`, once per compile of the
+    layer (a scan's body traces once for all its layers), not per call.
+    """
     m = cfg.moe
     B, S, d = x.shape
     xt = x.reshape(B * S, d)
     gates, idx, aux = route(params["router"], xt, m, params.get(BIAS))
-    out, routed = held_experts(xt, idx, gates, params["w_gate"],
-                               params["w_up"], params["w_down"],
-                               m.first_held)
+    w = (params["w_gate"], params["w_up"], params["w_down"])
+    path = experts_path(B * S, m.top_k, m.n_experts, None, _device_peaks(),
+                        w[0].dtype.itemsize)
+    default_registry().counter(f"moe.experts.{path}").inc()
+    held = held_experts_dense if path == "dense" else held_experts
+    out, routed = held(xt, idx, gates, *w, m.first_held)
     out = out.reshape(B, S, d) + _shared(params.get("shared"), x)
     return out, aux, routed
 

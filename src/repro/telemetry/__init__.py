@@ -96,6 +96,9 @@ Where each span/metric hangs (the observability map):
                        `engine.prepare` per derivation of the serving
                        weights (attrs `cast_leaves`, `kept_leaves`,
                        `cast_bytes`).
+  moe_dense            counters `moe.experts.dense` / `moe.experts.ragged`:
+  (repro.models.moe)   one per trace of the layer onto that form of the
+                       held experts (per compile, not per step).
   HBMPlanner           span `planner.profile` per ladder point (attrs
   (repro.core)         `n_layers`, `seq_len`, `batch`) with children
                        `planner.lower` / `.compile` / `.memory`, inside

@@ -152,22 +152,49 @@ def test_ep32_share_is_registered():
     assert share.moe.d_ff_expert == full.moe.d_ff_expert
 
 
-@pytest.mark.parametrize("rows", [None, 16, 12, 5])
-def test_held_experts_rows_bound(rows):
+# (form, rows, unreached): the ragged form with no bound or a bound on
+# rows, and the dense form, each also with one held expert no pair reaches
+HELD_CASES = [
+    pytest.param("ragged", None, False, id="None"),
+    pytest.param("ragged", 16, False, id="16"),
+    pytest.param("ragged", 12, False, id="12"),
+    pytest.param("ragged", 5, False, id="5"),
+    pytest.param("ragged", None, True, id="None-unreached"),
+    pytest.param("dense", None, False, id="dense"),
+    pytest.param("dense", None, True, id="dense-unreached"),
+]
+
+
+@pytest.mark.parametrize("form, rows, unreached", HELD_CASES)
+def test_held_experts_rows_bound(form, rows, unreached):
     """The held-experts layer over 8 tokens x top-2 routed among 8 experts,
-    4 held from expert 2. With no bound, or one the held pairs fit, it
-    equals each held pair's gated SwiGLU summed per token; with fewer rows
-    it drops the held pairs past them, in the order sorted by expert."""
+    4 held from expert 2: pairs routed outside the held range, tokens with
+    two held pairs, and (`unreached`) held expert 5 with none. With no
+    bound, or one the held pairs fit, it equals each held pair's gated
+    SwiGLU summed per token; with fewer rows it drops the held pairs past
+    them, in the order sorted by expert. The dense form equals the ragged
+    one and routes the same pairs."""
     import jax
     rng = np.random.default_rng(3)
     T, k, d, f, E, first = 8, 2, 6, 5, 4, 2
     xt = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
     idx = np.stack([rng.choice(8, k, replace=False) for _ in range(T)])
+    if unreached:
+        # the tokens routed to 5 hold no 7: still k distinct experts
+        idx = np.where(idx == 5, 7, idx)
     gates = jnp.asarray(rng.uniform(0.1, 1.0, (T, k)), jnp.float32)
     wg, wu, wd = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in (
         (E, d, f), (E, d, f), (E, f, d)))
-    out, routed = M.held_experts(xt, jnp.asarray(idx), gates, wg, wu, wd,
-                                 first, rows)
+    args = (xt, jnp.asarray(idx), gates, wg, wu, wd, first)
+    if form == "dense":
+        out, routed = M.held_experts_dense(*args)
+        ragged, ragged_routed = M.held_experts(*args)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ragged),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(routed),
+                                      np.asarray(ragged_routed))
+    else:
+        out, routed = M.held_experts(*args, rows)
     held = [(t, j) for e in range(E) for t in range(T) for j in range(k)
             if idx[t, j] == first + e]
     kept = held if rows is None else held[:rows]
@@ -179,5 +206,107 @@ def test_held_experts_rows_bound(rows):
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-5, rtol=1e-5)
     np.testing.assert_array_equal(
         np.asarray(routed), [(idx == first + e).sum() for e in range(E)])
+    assert any(np.isin(idx[t], range(first, first + E)).all()
+               for t in range(T))
+    assert not np.isin(idx, range(first, first + E)).all()
     # 16 rows hold every pair; the 9 held pairs fit 12 rows, not 5
-    assert len(held) == 9 and (rows == 5) == (len(kept) < len(held))
+    assert len(held) == (7 if unreached else 9)
+    assert (rows == 5) == (len(kept) < len(held))
+    assert (np.asarray(routed) == 0).any() == unreached
+
+
+V5E = M.PEAKS["TPU v5 lite"]
+
+
+@pytest.mark.parametrize("T, rows, kind, path", [
+    (128, None, "TPU v5 lite", "dense"),     # dsv3.decode: 128 tokens
+    (240, None, "TPU v5 lite", "dense"),     # the v5e ridge at bf16: 240.5
+    (241, None, "TPU v5 lite", "ragged"),
+    (4, None, "TPU v5 lite", "ragged"),      # 32 pairs < 256 experts
+    (1024, None, "TPU v5 lite", "ragged"),   # over the ridge
+    (128, 40, "TPU v5 lite", "ragged"),      # a bound on rows
+    (128, None, "cpu", "ragged"),            # a kind PEAKS lacks
+])
+def test_experts_path(T, rows, kind, path):
+    """The held experts run dense only while every held expert expects a
+    pair, nothing bounds the rows and the tokens are at most the chip's
+    ridge; DeepSeek-V3's top-8 of 256 at bf16."""
+    assert M.experts_path(T, 8, 256, rows, M.PEAKS.get(kind), 2) == path
+
+
+@pytest.fixture
+def registry():
+    from repro.telemetry import MetricsRegistry, set_default_registry
+    reg = MetricsRegistry()
+    prev = set_default_registry(reg)
+    yield reg
+    set_default_registry(prev)
+
+
+def counts(reg):
+    c = reg.snapshot()["counters"]
+    return (c.get("moe.experts.dense", 0), c.get("moe.experts.ragged", 0))
+
+
+def test_decode_step_same_on_either_path(monkeypatch, registry):
+    """`Model.decode_step` of the reduced EP32 share (4 of 8 experts held,
+    top-2) in bfloat16 gives the same logits and routes the same pairs
+    with the rule sending the held experts either way; each trace counts
+    the path it took."""
+    import jax
+    from repro.configs.base import RunConfig
+    from repro.models.model import Model
+    cfg = get_arch("deepseek-v3-671b-ep32").reduced()
+    model = Model(cfg, RunConfig(attn_impl="full", remat="nothing"))
+    params = model.init(jax.random.PRNGKey(0))
+    B = 8
+    caches = model.init_caches(B, 16)
+    batch = {"tokens": jnp.arange(1, B + 1, dtype=jnp.int32)[:, None] * 7}
+    got = {}
+    for path in ("ragged", "dense"):
+        monkeypatch.setattr(M, "experts_path", lambda *a, p=path: p)
+        before = counts(registry)
+        logits, _, routed = jax.jit(
+            lambda p, b, c: model.decode_step(p, b, c, routed=True))(
+                params, batch, caches)
+        after = counts(registry)
+        taken = [a - b for a, b in zip(after, before)]
+        assert taken[("dense", "ragged").index(path)] > 0
+        assert taken[("ragged", "dense").index(path)] == 0
+        got[path] = (np.asarray(logits, np.float32), np.asarray(routed))
+    assert got["dense"][1].sum() > 0
+    np.testing.assert_array_equal(got["dense"][1], got["ragged"][1])
+    np.testing.assert_allclose(got["dense"][0], got["ragged"][0],
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("impl, T, ragged", [
+    ("dense", 128, False),      # decode shapes: every held expert reached
+    ("dense", 1024, True),      # over the ridge
+    ("ep_tp", 128, True),       # the shard bodies bound their rows
+    ("ep_a2a", 128, True),
+])
+def test_ragged_dot_where_the_rule_keeps_it(monkeypatch, registry, impl, T,
+                                            ragged):
+    """On a v5e (its peaks patched in for the host's device) the one-device
+    layer traces no `ragged_dot` at decode shapes, and still does over the
+    ridge; the expert-parallel shard bodies always do, and never consult
+    the rule."""
+    import dataclasses
+    import jax
+    from repro.configs.base import RunConfig
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setattr(M, "_device_peaks", lambda: V5E)
+    cfg = get_arch("deepseek-v3-671b-ep32").reduced()   # top-2 of 8
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           impl=impl))
+    params = M.init_moe(jax.random.PRNGKey(0), cfg)
+    x = jnp.zeros((T // 4, 4, cfg.d_model), jnp.bfloat16)
+    mesh = None if impl == "dense" else make_mesh((1, 1), ("data", "model"))
+    run = RunConfig(attn_impl="full", remat="nothing")
+    text = str(jax.make_jaxpr(lambda p, x: M.moe(p, x, cfg, run, mesh))(
+        params, x))
+    assert ("ragged_dot" in text) == ragged
+    dense, rag = counts(registry)
+    assert (dense, rag) == ((0, 0) if impl != "dense" else
+                            (0, 1) if ragged else (1, 0))
