@@ -12,8 +12,8 @@ process.
               deepest ladder depth, decodes a cut of decode_32k on real
               parameters and caches; measured HBM beside compiled bytes.
               First, because the peak is a process-wide high-water mark
-  2 allocate  one request through AllocationEndpoint; its profile_at
-              compiles each ladder point for this chip
+  2 allocate  one request through AllocationPipeline; the planner's
+              profile_at compiles each ladder point for this chip
   3 train     launch/train.py on whisper-small, published widths and depth
   4 serve     launch/serve.py on whisper-small at published widths
   5 kernels   each Pallas kernel at the widths of a config that uses it,
@@ -155,52 +155,37 @@ def decode_phase(dev, cfg, shape, full_depth: int) -> None:
 
 # -- phase 2 ----------------------------------------------------------------
 def allocate_phase(dev, cfg, shape) -> None:
-    from repro.allocator.service import AllocationService
     from repro.core.catalog import tpu_catalog
-    from repro.core.hbm_planner import (HBMPlanner, TPU_OVERHEAD_GIB,
-                                        _reduced_depth)
+    from repro.core.hbm_planner import HBMPlanner, TPU_OVERHEAD_GIB
     from repro.core.history import ExecutionHistory
-    from repro.core.profiler import ProfileResult
-    from repro.serve.engine import AllocationEndpoint
+    from repro.pipeline import AllocationPipeline, PipelineRequest
 
-    mesh = one_chip_mesh(dev)
     planner = HBMPlanner()
-    points = []
-
-    def profile_at(size: float) -> ProfileResult:
-        t0 = time.monotonic()
-        small = _reduced_depth(cfg, int(round(size)))
-        per_dev = planner.profile_memory(small, shape, mesh)
-        wall = time.monotonic() - t0
-        points.append((small.n_layers, per_dev, wall))
-        return ProfileResult(size, per_dev, 0.0, wall)
-
     # the planner's integer depth ladder; its anchor is the deepest point
     ladder = planner.ladder(cfg)
-    catalog = tpu_catalog()
-    with AllocationService(catalog, ExecutionHistory(),
-                           overhead_per_node_gib=TPU_OVERHEAD_GIB) as svc:
-        wire = AllocationEndpoint(svc).handle(
-            job=f"{cfg.name}:{shape.name}:seq{shape.seq_len}xb"
-                f"{shape.global_batch}",
-            profile_at=profile_at, full_size=cfg.n_layers,
-            anchor=ladder[-1], sizes=ladder)
-    chips = next(c.scale_out for c in catalog if c.name == wire["config"])
-    points.sort()
-    log(f"[2 allocate] job={wire['job']} full_size={cfg.n_layers} "
+    tr = AllocationPipeline(
+        tpu_catalog(), ExecutionHistory(),
+        overhead_per_node_gib=TPU_OVERHEAD_GIB).run(PipelineRequest(
+            f"{cfg.name}:{shape.name}:seq{shape.seq_len}xb"
+            f"{shape.global_batch}",
+            planner.profile_at(cfg, shape, one_chip_mesh(dev)),
+            full_size=cfg.n_layers, sizes=ladder))
+    config = tr.selection.config
+    log(f"[2 allocate] job={tr.job} full_size={cfg.n_layers} "
         f"anchor={ladder[-1]} ladder={ladder} "
-        f"per_dev={[gib(b) for _, b, _ in points]} "
-        f"compile_s={[round(w, 1) for _, _, w in points]}; "
-        f"requirement_gib={wire['requirement_gib']:.2f} "
-        f"config={wire['config']} ({chips} chips) source={wire['source']} "
-        f"profiled={wire['profiled']} wall_s={wire['wall_s']:.1f}")
-    if wire["profiled"] < 2:
-        raise RuntimeError(f"only {wire['profiled']} ladder points profiled")
-    if wire["source"] != "zoo":
-        raise RuntimeError(f"no confident memory model ({wire['source']})")
-    if chips <= 1:
+        f"per_dev={[gib(r.peak_mem_bytes) for r in tr.results]} "
+        f"compile_s={[round(r.wall_s, 1) for r in tr.results]}; "
+        f"requirement_gib={tr.requirement_gib:.2f} "
+        f"config={config.name} ({config.scale_out} chips) "
+        f"source={tr.source} profiled={tr.plan.profiled} "
+        f"wall_s={tr.wall_s:.1f}")
+    if tr.plan.profiled < 2:
+        raise RuntimeError(f"only {tr.plan.profiled} ladder points profiled")
+    if tr.source != "zoo":
+        raise RuntimeError(f"no confident memory model ({tr.source})")
+    if config.scale_out <= 1:
         raise RuntimeError(f"{cfg.n_layers} layers cannot fit one chip, "
-                           f"yet {wire['config']} was selected")
+                           f"yet {config.name} was selected")
 
 
 # -- phases 3 and 4 ---------------------------------------------------------
@@ -325,18 +310,22 @@ def four_chip_phase(devs, cfg, train_shape, dshape) -> None:
     logits on four chips against one chip."""
     import jax
     import numpy as np
-    from repro.core.hbm_planner import (HBMPlanner, _reduced_depth,
-                                        compiled_bytes)
+    from repro.core.catalog import tpu_catalog
+    from repro.core.hbm_planner import (HBMPlanner, TPU_OVERHEAD_GIB,
+                                        _reduced_depth, compiled_bytes)
+    from repro.core.history import ExecutionHistory
     from repro.launch.dryrun import build_lowered
     from repro.launch.mesh import make_mesh, mesh_config
     from repro.launch.presets import preset_run
     from repro.optim import AdamWConfig
+    from repro.pipeline import AllocationPipeline, PipelineRequest
     from repro.train.step import init_train_state
 
     devs = devs[:4]
     mesh = make_mesh((1, 4), ("data", "model"), devices=devs)
     planner = HBMPlanner()
-    deepest = _reduced_depth(cfg, planner.ladder(cfg)[-1])
+    ladder = planner.ladder(cfg)
+    deepest = _reduced_depth(cfg, ladder[-1])
     limit = min(mem_stats(d)["bytes_limit"] for d in devs)
 
     # cut the train batch until the deepest ladder point fits a chip. The
@@ -355,16 +344,21 @@ def four_chip_phase(devs, cfg, train_shape, dshape) -> None:
             raise RuntimeError("the deepest point fits no batch")
         train_shape = dataclasses.replace(
             train_shape, seq_len=train_shape.seq_len // 2)
-    rep = planner.plan(cfg, train_shape, mesh)
-    sel = rep.selection
-    log(f"[4x ladder] mesh (1, 4) data x model; ladder={rep.ladder} "
-        f"per_dev={[gib(b) for b in rep.per_dev_bytes]} "
-        f"R2={rep.model.r2:.5f} predicted at {cfg.n_layers} layers "
-        f"{rep.predicted_per_dev_gib:.2f}GiB/chip, requirement "
-        f"{rep.requirement_gib:.1f}GiB -> {sel.config.name}; "
+    tr = AllocationPipeline(
+        tpu_catalog(), ExecutionHistory(),
+        overhead_per_node_gib=TPU_OVERHEAD_GIB, leeway=0.05).run(
+            PipelineRequest(f"{cfg.name}:{train_shape.name}",
+                            planner.profile_at(cfg, train_shape, mesh),
+                            full_size=cfg.n_layers, sizes=ladder))
+    fit = tr.plan.fit
+    log(f"[4x ladder] mesh (1, 4) data x model; ladder={ladder} "
+        f"per_dev={[gib(b / len(devs)) for b in tr.mems]} "
+        f"R2={fit.r2:.5f} predicted at {cfg.n_layers} layers "
+        f"{tr.requirement_gib / len(devs):.2f}GiB/chip, requirement "
+        f"{tr.requirement_gib:.1f}GiB -> {tr.selection.config.name}; "
         f"wall {time.monotonic() - t0:.1f}s")
-    if not rep.model.confident:
-        raise RuntimeError(f"ladder fit not confident: R2={rep.model.r2}")
+    if not fit.confident:
+        raise RuntimeError(f"ladder fit not confident: R2={fit.r2}")
 
     # measured peaks: one train step of the deepest point on the mesh
     lowered, model = build_lowered(deepest, train_shape, mesh)

@@ -1,6 +1,8 @@
 """Crispy for TPU slices: before launching an (arch x shape) job, profile
-five reduced-depth compiles on this CPU host, extrapolate per-device HBM to
-the full depth, and pick the cheapest feasible slice from the TPU catalog.
+reduced-depth compiles on this host, extrapolate the job's HBM to the full
+depth, and pick the cheapest feasible slice from the TPU catalog — one
+request through `AllocationPipeline`, with the planner as its profile
+source.
 
   PYTHONPATH=src python examples/mesh_advisor.py --arch deepseek-7b
 """
@@ -9,8 +11,11 @@ import dataclasses
 
 from repro.configs import SHAPES, get_arch
 from repro.configs.base import RunConfig
-from repro.core.hbm_planner import HBMPlanner
+from repro.core.catalog import tpu_catalog
+from repro.core.hbm_planner import HBMPlanner, TPU_OVERHEAD_GIB
+from repro.core.history import ExecutionHistory
 from repro.launch.mesh import make_mesh
+from repro.pipeline import AllocationPipeline, PipelineRequest
 
 GiB = 1024 ** 3
 
@@ -33,29 +38,32 @@ def main(argv=None):
                     compute_dtype="bfloat16", microbatches=2)
     mesh = make_mesh((1, 1), ("data", "model"))
 
-    planner = HBMPlanner(leeway=0.05)
-    rep = planner.plan(cfg, shape, mesh, run=run, anchor_layers=12)
-    print(f"arch={cfg.name} layers ladder={rep.ladder}")
-    print(f"per-device bytes at ladder: "
-          f"{[f'{m / 2**20:.1f}MiB' for m in rep.per_dev_bytes]}")
-    print(f"OLS: slope={rep.model.slope / 2**20:.2f} MiB/layer, "
-          f"intercept={rep.model.intercept / 2**20:.1f} MiB, "
-          f"R2={rep.model.r2:.5f} "
-          f"({'PASS' if rep.model.confident else 'fallback'})")
-    print(f"extrapolated to {cfg.n_layers} layers: "
-          f"{rep.predicted_per_dev_gib:.3f} GiB/device "
-          f"-> aggregate requirement {rep.requirement_gib:.2f} GiB")
-    sel = rep.selection
+    planner = HBMPlanner()
+    tr = AllocationPipeline(
+        tpu_catalog(), ExecutionHistory(),
+        overhead_per_node_gib=TPU_OVERHEAD_GIB, leeway=0.05).run(
+            PipelineRequest(f"{cfg.name}:{shape.name}",
+                            planner.profile_at(cfg, shape, mesh, run),
+                            full_size=cfg.n_layers,
+                            sizes=planner.ladder(cfg, 12)))
+    fit = tr.plan.fit
+    print(f"arch={cfg.name} layers ladder={[int(s) for s in tr.sizes]}")
+    print(f"bytes at ladder: {[f'{m / 2**20:.1f}MiB' for m in tr.mems]}")
+    print(f"fit: {fit.candidate}, R2={fit.r2:.5f} "
+          f"({'PASS' if fit.confident else 'fallback'})")
+    print(f"extrapolated to {cfg.n_layers} layers: requirement "
+          f"{tr.requirement_gib:.3f} GiB ({tr.source})")
+    sel = tr.selection
     print(f"selected: {sel.config.name} "
           f"({sel.config.total_mem_gib:.0f} GiB HBM, "
           f"${sel.config.usd_per_hour:.2f}/h; "
           f"{sel.feasible_count} feasible configs"
           f"{'; fell back' if sel.fell_back else ''})")
-    # ground truth check
+    # ground truth check, without the leeway the requirement carries
     truth = planner.profile_memory(cfg, shape, mesh, run)
-    err = abs(rep.predicted_per_dev_gib * GiB - truth) / truth
+    pred = fit.predict(cfg.n_layers)
     print(f"ground-truth full compile: {truth / GiB:.3f} GiB/device "
-          f"(extrapolation error {err:.2%})")
+          f"(extrapolation error {abs(pred - truth) / truth:.2%})")
 
 
 if __name__ == "__main__":

@@ -273,8 +273,8 @@ class ZooFit(GatedMemoryModel):
     n: int
     loocv_gate: float = LOOCV_GATE
     fits: Optional[Dict[str, object]] = None   # kind -> fitted candidate
-                                     # (all of them — the adaptive
-                                     # scheduler's disagreement check
+                                     # (all of them — adaptive
+                                     # placement's disagreement check
                                      # reads their full-size predictions
                                      # without refitting)
 

@@ -304,7 +304,6 @@ class AllocationService:
                  history: ExecutionHistory,
                  registry: Optional[ModelRegistry] = None,
                  classifier: Optional[NearestJobClassifier] = None,
-                 candidates: Optional[Sequence] = None,
                  overhead_per_node_gib: float = DEFAULT_OVERHEAD_GIB,
                  leeway: float = 0.0,
                  profile_cache_size: int = 512,
@@ -364,7 +363,7 @@ class AllocationService:
         from repro.pipeline import AllocationPipeline
         self.pipeline = AllocationPipeline(
             catalog, history, registry=self.registry,
-            classifier=self.classifier, candidates=candidates,
+            classifier=self.classifier,
             overhead_per_node_gib=overhead_per_node_gib, leeway=leeway,
             adaptive=adaptive, placement=placement, budget=budget,
             store=store, executor=executor, cache=self._cache,

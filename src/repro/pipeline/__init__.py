@@ -82,7 +82,11 @@ Entry points driving the pipeline:
     this with a parity contract: service and one-shot answers over the
     same backend are byte-identical);
   * `examples/profile_and_select.py`, `benchmarks/point_placement.py` —
-    direct `AllocationPipeline.run()` users.
+    direct `AllocationPipeline.run()` users;
+  * TPU jobs: `HBMPlanner.profile_at` (core/hbm_planner.py) is the
+    profile source, with the TPU catalog and `TPU_OVERHEAD_GIB`
+    (chip_smoke.py, `examples/mesh_advisor.py`,
+    `benchmarks/planner_validation.py`).
 
 Shared state composes exactly as before: `store=` (ProfileStore over any
 repro.state backend), `budget=` (ProfilingBudget, shared-envelope aware),

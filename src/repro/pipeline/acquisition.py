@@ -1,16 +1,12 @@
 """Point acquisition: ONE cache-hierarchy + budget gate for every caller.
 
-Before the pipeline existed, three code paths each re-implemented "get a
-profile point without paying twice": the AllocationService (LRU -> store
--> fresh run), the AdaptiveLadderScheduler's `take()`, and the
-ProfilingExecutor's `one()`. They mostly agreed — but the one-shot
-CrispyAllocator path never refreshed its ProfileStore, so points a
-sibling process had already profiled (and charged to a shared
-ProfilingBudget envelope) were invisible, re-profiled, and charged a
-second time. `PointSource` is now the only implementation of the rule:
+`PointSource` is the only implementation of "get a profile point without
+paying twice"; `AllocationPipeline` acquires every point through it, on
+the fixed ladder and under adaptive placement (`drive_placement`) alike:
 
-  peek (LRU, then shared store — refreshed once per acquisition) is
-  consulted BEFORE the budget gate, so cached work is always free;
+  the cache hierarchy (LRU, then shared store — refreshed once per
+  acquisition) is consulted BEFORE the budget gate, so cached work is
+  always free;
   only a genuinely fresh run reserves a budget point and charges its
   reported wall seconds; a reservation that races another thread's
   fresh run is refunded, never charged.
@@ -74,23 +70,6 @@ class PointSource:
                 store.refresh()
             except Exception:
                 pass                         # a stale view is still correct
-
-    # -- cache hierarchy ----------------------------------------------------
-    def peek(self, size: float) -> Optional[ProfileResult]:
-        """LRU then shared store; no profiling, no budget interaction.
-        Does NOT count hits (acquire() does) — safe for budget gates and
-        schedulers to call speculatively."""
-        if self.cache is not None:
-            r = self.cache.get(self.signature, size)
-            if r is not None:
-                return r
-        if self.store is not None:
-            r = self.store.get(self.signature, size)
-            if r is not None:
-                if self.cache is not None:
-                    self.cache.put(self.signature, size, r, from_store=True)
-                return r
-        return None
 
     def _record_hit(self, from_store: bool) -> None:
         with self._lock:
@@ -156,22 +135,6 @@ class PointSource:
                 pass            # a write-through failure costs a future
                                 # re-profile, never this plan
         return r, True
-
-    # -- legacy ProfilePointFn adapter --------------------------------------
-    def as_point_fn(self):
-        """The `(size) -> (result, fresh)` callable (with `.peek`) the
-        PR-2 scheduler/executor interfaces expect, WITHOUT their budget
-        handling: this source already gates and charges, so callers must
-        not pass a budget of their own alongside it."""
-        def pp(size: float) -> Tuple[ProfileResult, bool]:
-            got = self.acquire(size)
-            if got is None:
-                from repro.profiling.budget import BudgetExhausted
-                raise BudgetExhausted(
-                    f"budget denied point {size!r} for {self.signature!r}")
-            return got
-        pp.peek = self.peek
-        return pp
 
 
 @dataclass

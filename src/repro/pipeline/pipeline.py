@@ -151,8 +151,6 @@ class AllocationPipeline:
                  registry=None,             # allocator ModelRegistry (or None)
                  classifier=None,           # NearestJobClassifier (or None)
                  fitter: Optional[Callable] = None,
-                 candidates: Optional[Sequence] = None,
-                 runtime_fitter: Optional[Callable] = None,
                  overhead_per_node_gib: float = DEFAULT_OVERHEAD_GIB,
                  leeway: float = 0.0,
                  adaptive: bool = False,
@@ -175,8 +173,6 @@ class AllocationPipeline:
         self.registry = registry
         self.classifier = classifier
         self.fitter = fitter
-        self.candidates = candidates
-        self.runtime_fitter = runtime_fitter
         self.overhead = overhead_per_node_gib
         self.leeway = leeway
         self.adaptive = adaptive
@@ -239,12 +235,7 @@ class AllocationPipeline:
     def _fit(self, sizes: Sequence[float], mems: Sequence[float]):
         if self.fitter is not None:
             return self.fitter(sizes, mems)
-        return fit_zoo(sizes, mems, self.candidates)
-
-    def _fit_runtime(self, sizes: Sequence[float], walls: Sequence[float]):
-        if self.runtime_fitter is not None:
-            return self.runtime_fitter(sizes, walls)
-        return fit_runtime_zoo(sizes, walls)
+        return fit_zoo(sizes, mems)
 
     # -- stage 1: warm start ------------------------------------------------
     def warm_start(self, signature: str) -> Optional[PipelinePlan]:
@@ -344,7 +335,7 @@ class AllocationPipeline:
             t_rt = perf_counter()
             with span_if(tel.enabled, "pipeline.fit_runtime",
                          signature=sig):
-                runtime_fit = self._fit_runtime(sizes, walls)
+                runtime_fit = fit_runtime_zoo(sizes, walls)
             fit_wall[0] += perf_counter() - t_rt
         self._stage_hist["acquire"].observe(acquire_wall)
         self._stage_hist["fit"].observe(fit_wall[0])

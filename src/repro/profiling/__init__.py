@@ -9,25 +9,15 @@ package turns profiling itself into a managed resource:
                 enforced, thread-safe limit (wall clock, accounted profile
                 seconds, and point count) shared by everything below.
 
-  scheduler.py  `AdaptiveLadderScheduler` — now a budget-gating driver
-                over the `repro.pipeline` placement strategies: the PR-2
-                ladder-prefix behavior lives in
-                `repro.pipeline.placement.LadderPlacer` (smallest-first,
-                refit per point, early stop on confident+stable,
-                gap-midpoint escalation while candidates disagree;
-                Ruya-style iterative spend, arXiv:2211.04240), and
-                `placement="infogain"` swaps in information-optimal
-                placement. `calibrated_anchor` persists per-signature
-                anchors so repeat signatures skip `calibrate_anchor`
-                entirely.
-
   executor.py   `ProfilingExecutor` — thread pool the pipeline fans
                 fixed-ladder points and the service fans independent
                 signature groups over (`map_tasks`); budget gating lives
                 in the pipeline's acquisition stage, not here.
 
   store.py      `ProfileStore` (profile points + calibrated anchors in a
-                backend append-only log), `BackendModelRegistry`
+                backend append-only log; `calibrated_anchor` persists
+                per-signature anchors so repeat signatures skip
+                `calibrate_anchor` entirely), `BackendModelRegistry`
                 (read-merge-CAS registry flushes: concurrent services
                 lose no records) and the back-compat `LockedModelRegistry`
                 file constructor. All sharing is delegated to the
@@ -43,18 +33,12 @@ adaptive points, wall time and requirement error, and
 """
 from repro.profiling.budget import BudgetExhausted, ProfilingBudget
 from repro.profiling.executor import DEFAULT_WORKERS, ProfilingExecutor
-from repro.profiling.scheduler import (AdaptiveLadderScheduler,
-                                       AdaptiveProfile, DISAGREE_RTOL,
-                                       MAX_EXTRA_POINTS, MIN_POINTS,
-                                       STABILITY_RTOL, calibrated_anchor)
 from repro.profiling.store import (BackendModelRegistry, FileLock,
                                    HAS_FCNTL, LockedModelRegistry,
-                                   ProfileStore)
+                                   ProfileStore, calibrated_anchor)
 
 __all__ = [
-    "AdaptiveLadderScheduler", "AdaptiveProfile", "BackendModelRegistry",
-    "BudgetExhausted", "DEFAULT_WORKERS", "DISAGREE_RTOL", "FileLock",
-    "HAS_FCNTL", "LockedModelRegistry", "MAX_EXTRA_POINTS", "MIN_POINTS",
-    "ProfileStore", "ProfilingBudget", "ProfilingExecutor",
-    "STABILITY_RTOL", "calibrated_anchor",
+    "BackendModelRegistry", "BudgetExhausted", "DEFAULT_WORKERS",
+    "FileLock", "HAS_FCNTL", "LockedModelRegistry", "ProfileStore",
+    "ProfilingBudget", "ProfilingExecutor", "calibrated_anchor",
 ]

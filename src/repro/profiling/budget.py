@@ -4,9 +4,9 @@ Crispy's pitch is that profiling costs "less than ten minutes per job on a
 consumer-grade laptop" (§IV-B, Table II). The follow-up allocation study
 (arXiv:2306.03672) argues profiling itself must be treated as a budgeted
 resource: every profile point spent on one job is wall time unavailable to
-another. `ProfilingBudget` makes that envelope explicit and shared — the
-adaptive scheduler, the profiling executor and the AllocationService all
-check the same budget before spending a point.
+another. `ProfilingBudget` makes that envelope explicit and shared: the
+pipeline's `PointSource` checks it before every fresh point, for every
+caller that shares it.
 
 Three independent limits, any of which exhausts the budget:
 
@@ -22,7 +22,7 @@ Three independent limits, any of which exhausts the budget:
 Two sharing scopes:
 
   local (default)      thread-safe within one process: many executor
-                       workers / schedulers spend from one budget.
+                       workers spend from one budget.
   shared (backend=)    the counters live in a `repro.state.StateBackend`
                        document and every reserve/charge/refund goes
                        through the backend's atomic lease primitive

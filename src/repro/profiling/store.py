@@ -8,7 +8,8 @@ state in-process (InMemoryBackend), across processes on one host
 (DaemonBackend):
 
   ProfileStore           (signature, size) -> ProfileResult rows plus
-                         per-signature calibrated anchors, kept in a
+                         per-signature calibrated anchors (written by
+                         `calibrated_anchor`), kept in a
                          backend append-only log. Later rows win, so
                          readers never NEED compaction — but re-profiled
                          points and recalibrated anchors shadow earlier
@@ -62,11 +63,12 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.allocator.registry import (ModelRecord, ModelRegistry,
                                       REGISTRY_VERSION)
 from repro.core.profiler import ProfileResult
+from repro.core.sampling import calibrate_anchor
 from repro.state import FileBackend, StateBackend
 from repro.state.compaction import prune_registry_doc
 from repro.state.file_backend import FileLock, HAS_FCNTL  # noqa: F401 (compat)
@@ -664,3 +666,18 @@ class LockedModelRegistry(BackendModelRegistry):
         root, stem = _split_path(path, ".json")
         super().__init__(FileBackend(root, lock_timeout_s=lock_timeout_s),
                          namespace=stem, autosave=autosave, path=path)
+
+
+def calibrated_anchor(store, signature: str,
+                      run_at_size: Callable[[float], float],
+                      initial: float, **calibrate_kwargs) -> float:
+    """`calibrate_anchor` with persistence: a signature calibrated by any
+    process (or a past run) skips the measurement loop entirely."""
+    if store is not None:
+        known = store.get_anchor(signature)
+        if known is not None:
+            return known
+    anchor = calibrate_anchor(run_at_size, initial, **calibrate_kwargs)
+    if store is not None:
+        store.put_anchor(signature, anchor)
+    return anchor

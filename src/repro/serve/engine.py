@@ -3,8 +3,7 @@
 The engine keeps `slots` concurrent sequences. Each scheduler tick:
   1. admit queued requests into free slots (prompt tokens are injected
      through the decode path token-by-token — teacher-forced prefill — so
-     one compiled decode_step serves both phases; architectures with a
-     fused prefill use it via `prefill_into_slot`);
+     one compiled decode_step serves both phases, for every family);
   2. run one batched decode_step for all active slots;
   3. retire sequences that hit max tokens or EOS.
 
@@ -37,11 +36,9 @@ in a root span `engine.prepare` (attributes `cast_leaves`,
 `kept_leaves`, `cast_bytes` of the serving copy; its `wall_s` is the
 time the derivation took).
 
-`AllocationEndpoint` exposes the allocator subsystem
-(repro.allocator.service) on the same serving surface: dict-in/dict-out
-allocation requests, optionally attached to a `ServeEngine` via
-`attach_allocator` so one server answers both generation and
-resource-allocation traffic.
+`AllocationEndpoint` is the allocator's request surface
+(repro.allocator.service): dict-in/dict-out allocation requests. It is
+independent of `ServeEngine`, which is a job the allocator sizes.
 """
 from __future__ import annotations
 
@@ -75,7 +72,6 @@ class Request:
 class ServeEngine:
     def __init__(self, model: Model, params, slots: int, max_len: int,
                  eos_id: Optional[int] = None, seed: int = 0,
-                 allocator: Optional[AllocationService] = None,
                  telemetry: Optional[MetricsRegistry] = None):
         self.model = model
         self.slots = slots
@@ -88,11 +84,8 @@ class ServeEngine:
         self.finished: List[Request] = []
         self._feed: List[List[int]] = [[] for _ in range(slots)]
         self._last_token = np.zeros((slots,), np.int32)
-        self.allocation_endpoint: Optional[AllocationEndpoint] = None
         self.telemetry = telemetry if telemetry is not None \
             else default_registry()
-        if allocator is not None:
-            self.attach_allocator(allocator)
 
         self._routed = model.cfg.family == "moe"
 
@@ -128,19 +121,6 @@ class ServeEngine:
     # -- public ------------------------------------------------------------
     def submit(self, req: Request):
         self.pending.append(req)
-
-    def attach_allocator(self,
-                         service: AllocationService) -> "AllocationEndpoint":
-        """Expose an AllocationService next to the generation loop."""
-        self.allocation_endpoint = AllocationEndpoint(service)
-        return self.allocation_endpoint
-
-    def allocate(self, **payload) -> Dict:
-        """Answer one allocation request (see AllocationEndpoint.handle)."""
-        if self.allocation_endpoint is None:
-            raise RuntimeError("no AllocationService attached; call "
-                               "attach_allocator() first")
-        return self.allocation_endpoint.handle(**payload)
 
     def run(self, max_ticks: int = 10000) -> List[Request]:
         ticks = 0

@@ -394,7 +394,7 @@ def test_service_contains_no_pipeline_logic():
     import repro.allocator.service as service_mod
     src = open(service_mod.__file__).read()
     forbidden = ["fit_zoo", "fit_memory_model", "ladder_from_anchor",
-                 "select_crispy", "select_like", "AdaptiveLadderScheduler",
+                 "select_crispy", "select_like", "drive_placement",
                  "gap_midpoint", "calibrate_anchor", "model_zoo",
                  "requirement("]
     hits = [word for word in forbidden if word in src]
@@ -406,5 +406,5 @@ def test_crispy_wrapper_contains_no_pipeline_logic():
     import repro.core.crispy as crispy_mod
     src = open(crispy_mod.__file__).read()
     for word in ("fit_zoo", "ladder_from_anchor", "select_crispy",
-                 "AdaptiveLadderScheduler", "try_spend", "store.get("):
+                 "drive_placement", "try_spend", "store.get("):
         assert word not in src, word

@@ -1,7 +1,8 @@
-"""Profiling orchestration subsystem: adaptive ladder scheduling (early
-stop / escalation / budget exhaustion), the shared profiling budget, the
-file-locked multi-process profile & anchor store, the locked registry's
-merge-on-flush, and the AllocationService/CrispyAllocator/endpoint wiring.
+"""Profiling orchestration subsystem: adaptive ladder placement over the
+budgeted point source (early stop / escalation / budget exhaustion), the
+shared profiling budget, the file-locked multi-process profile & anchor
+store, the locked registry's merge-on-flush, and the
+AllocationService/CrispyAllocator/endpoint wiring.
 """
 import json
 import os
@@ -14,6 +15,7 @@ import pytest
 
 from repro.allocator import (AllocationRequest, AllocationService,
                              ModelRegistry)
+from repro.allocator.model_zoo import fit_zoo
 from repro.core.catalog import aws_like_catalog
 from repro.core.crispy import CrispyAllocator
 from repro.core.memory_model import fit_memory_model
@@ -21,22 +23,14 @@ from repro.core.profiler import ProfileResult
 from repro.core.sampling import integer_ladder, ladder_from_anchor
 from repro.core.simulator import (GiB, build_history, make_profile_fn,
                                   scout_like_jobs)
-from repro.profiling import (AdaptiveLadderScheduler, FileLock,
-                             LockedModelRegistry, ProfileStore,
+from repro.pipeline import LadderPlacer, PointSource, drive_placement
+from repro.profiling import (FileLock, LockedModelRegistry, ProfileStore,
                              ProfilingBudget, ProfilingExecutor,
                              calibrated_anchor)
 from repro.serve.engine import AllocationEndpoint
 
 FULL = 1e11
 LADDER = ladder_from_anchor(FULL * 0.01).sizes
-
-
-def _point_fn(mem_of_size, wall=10.0, calls=None):
-    def profile_point(s):
-        if calls is not None:
-            calls.append(s)
-        return ProfileResult(s, mem_of_size(s), 0.0, wall), True
-    return profile_point
 
 
 # -- budget -------------------------------------------------------------------
@@ -74,27 +68,39 @@ def test_budget_thread_safety():
     assert len(granted) == 100               # never over-granted
 
 
-# -- adaptive scheduler -------------------------------------------------------
+# -- adaptive placement over the budgeted point source ------------------------
+# the loop AllocationPipeline.measure_plan runs: LadderPlacer placement over
+# PointSource.acquire, which owns the budget gate and charge
+
+
+def _adaptive(mem_of_size, wall=10.0, calls=None, budget=None,
+              fit=fit_zoo):
+    def profile_at(s):
+        if calls is not None:
+            calls.append(s)
+        return ProfileResult(s, mem_of_size(s), 0.0, wall)
+    source = PointSource("sig", profile_at, budget=budget)
+    return drive_placement(LadderPlacer(), LADDER, FULL, source.acquire,
+                           fit)
 
 
 def test_early_stop_on_clean_linear_job():
     """A perfectly linear job must stop at <= 3 of the 5 ladder points and
     still extrapolate exactly."""
     calls = []
-    ap = AdaptiveLadderScheduler().run(
-        LADDER, FULL, _point_fn(lambda s: 0.9 * s + 1.6e9, calls=calls))
+    ap = _adaptive(lambda s: 0.9 * s + 1.6e9, calls=calls)
     assert ap.early_stop
-    assert ap.total_points <= 3 < len(LADDER)
-    assert len(calls) == ap.points == ap.total_points
+    assert len(ap.sizes) <= 3 < len(LADDER)
+    assert len(calls) == ap.fresh == len(ap.sizes)
     assert ap.fit.confident
     truth = 0.9 * FULL + 1.6e9
     assert abs(ap.fit.predict(FULL) - truth) / truth < 1e-6
     # smallest-first: the points profiled are the cheapest ladder prefix
-    assert ap.sizes == sorted(LADDER)[:ap.total_points]
+    assert ap.sizes == sorted(LADDER)[:len(ap.sizes)]
 
 
 def test_escalation_on_noisy_job():
-    """Noisy data: candidates disagree at full size, the scheduler spends
+    """Noisy data: candidates disagree at full size, the placer spends
     extra points beyond the base ladder, and stays unconfident."""
     rng = np.random.default_rng(3)
     noise = {}
@@ -104,9 +110,9 @@ def test_escalation_on_noisy_job():
             noise[s] = 1 + rng.normal(0, 0.09)
         return s * noise[s]
 
-    ap = AdaptiveLadderScheduler().run(LADDER, FULL, _point_fn(mem))
+    ap = _adaptive(mem)
     assert ap.escalated
-    assert ap.total_points > len(LADDER)
+    assert len(ap.sizes) > len(LADDER)
     assert not ap.early_stop
     assert not ap.fit.confident              # degrades like the paper
     assert ap.fit.requirement(FULL) == 0.0
@@ -116,16 +122,14 @@ def test_escalation_on_noisy_job():
 
 def test_budget_exhaustion_mid_ladder_falls_back_gracefully():
     budget = ProfilingBudget(max_points=2)
-    ap = AdaptiveLadderScheduler(budget=budget).run(
-        LADDER, FULL, _point_fn(lambda s: 0.9 * s))
+    ap = _adaptive(lambda s: 0.9 * s, budget=budget)
     assert ap.budget_exhausted
-    assert ap.total_points == 2
+    assert len(ap.sizes) == 2
     assert not ap.fit.confident              # 2 points never pass LOOCV
     assert ap.fit.requirement(FULL) == 0.0   # -> BFA fallback downstream
     # a budget that denies even the first point still returns a fit object
-    ap0 = AdaptiveLadderScheduler(budget=ProfilingBudget(max_points=0)).run(
-        LADDER, FULL, _point_fn(lambda s: s))
-    assert ap0.budget_exhausted and ap0.total_points == 0
+    ap0 = _adaptive(lambda s: s, budget=ProfilingBudget(max_points=0))
+    assert ap0.budget_exhausted and len(ap0.sizes) == 0
     assert not ap0.fit.confident
 
 
@@ -134,37 +138,20 @@ def test_budget_charges_reported_profile_seconds():
     seconds; charging the *reported* seconds reproduces the envelope."""
     rng = np.random.default_rng(11)
     budget = ProfilingBudget(charge_s=25.0)
-    ap = AdaptiveLadderScheduler(budget=budget).run(
-        LADDER, FULL,
-        _point_fn(lambda s: s * (1 + rng.normal(0, 0.2)), wall=10.0))
+    ap = _adaptive(lambda s: s * (1 + rng.normal(0, 0.2)), wall=10.0,
+                   budget=budget)
     # 10s per run: the third try_spend sees 20s charged < 25s, the fourth
     # sees 30s and is denied (noisy data never early-stops before then)
-    assert ap.total_points == 3
+    assert len(ap.sizes) == 3
     assert ap.budget_exhausted
     assert budget.charged_s == 30.0
 
 
 def test_scheduler_with_papers_linear_fitter():
     """A custom (non-zoo) fitter drives the same early-stop logic."""
-    ap = AdaptiveLadderScheduler(fitter=fit_memory_model).run(
-        LADDER, FULL, _point_fn(lambda s: 2.0 * s))
-    assert ap.early_stop and ap.total_points <= 3
+    ap = _adaptive(lambda s: 2.0 * s, fit=fit_memory_model)
+    assert ap.early_stop and len(ap.sizes) <= 3
     assert ap.fit.confident
-
-
-def test_scheduler_knobs_apply_to_named_placement():
-    """min_points/stability_rtol/... must reach the placer a placement
-    NAME builds; an explicit placer instance keeps its own knobs."""
-    from repro.pipeline import LadderPlacer
-    s1 = AdaptiveLadderScheduler(stability_rtol=0.01, max_extra_points=0,
-                                 placement="ladder")
-    assert s1.placer.stability_rtol == 0.01
-    assert s1.placer.max_extra_points == 0
-    s2 = AdaptiveLadderScheduler(min_points=4, placement="infogain")
-    assert s2.placer.name == "infogain" and s2.placer.min_points == 4
-    mine = LadderPlacer(stability_rtol=0.2)
-    assert AdaptiveLadderScheduler(stability_rtol=0.01,
-                                   placement=mine).placer is mine
 
 
 # -- persistent store ---------------------------------------------------------
